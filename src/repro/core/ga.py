@@ -45,6 +45,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.kernels.platform import on_tpu, pallas_interpret
+
 from .nfd import nfd_from_scratch, nfd_repack
 from .problem import (
     DEFAULT_INVENTORY_PENALTY,
@@ -281,12 +283,7 @@ class GeneticPacker:
     def _resolve_backend(self) -> str:
         if self.backend != "auto":
             return self.backend
-        try:
-            import jax
-
-            return "pallas" if jax.default_backend() == "tpu" else "ref"
-        except Exception:
-            return "python"
+        return "pallas" if on_tpu() else "ref"
 
     def _mutate(
         self,
@@ -331,20 +328,18 @@ class GeneticPacker:
 
         from repro.kernels.binpack_fitness.ops import population_costs
 
-        interpret = backend == "pallas" and _default_jax_backend() != "tpu"
         if Km is None:
             # single-kind: the problem's own mode table (equal to
             # BRAM18_MODES on default problems, so the jit cache is shared)
             totals = population_costs(
                 jnp.asarray(W), jnp.asarray(H), modes=modes,
-                backend=backend, interpret=interpret, mesh=mesh,
+                backend=backend, mesh=mesh,
             )
         else:
             totals = population_costs(
                 jnp.asarray(W),
                 jnp.asarray(H),
                 backend=backend,
-                interpret=interpret,
                 kinds=jnp.asarray(Km),
                 kind_tables=kind_tables,
                 mesh=mesh,
@@ -575,6 +570,7 @@ class GeneticPacker:
                 p_adm_h=self.p_adm_h,
                 seed=self.seed,
                 backend=run.backend,
+                interpret=pallas_interpret(run.backend),
                 **extra,
             ),
         )
@@ -817,11 +813,3 @@ class _GARun:
     def __init__(self):
         self.done = False
 
-
-def _default_jax_backend() -> str:
-    try:
-        import jax
-
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        return "cpu"

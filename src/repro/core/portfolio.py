@@ -48,6 +48,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.kernels.platform import pallas_interpret
+
 from .dse import _shard_devices, shard_chunks
 from .ga import (
     GeneticPacker,
@@ -617,6 +619,16 @@ def _group_label(group, i: int) -> str:
     return f"g{i}:single" if group.single else f"g{i}:scalar"
 
 
+def _group_backend(group) -> str:
+    """The evaluation backend a group's kernel calls resolved to (the scalar
+    loop calls no kernel: ``"python"``)."""
+    if isinstance(group, _SAFleetGroup):
+        return group.sts[0].backend
+    if isinstance(group, _GAGroup):
+        return group.pairs[0][1].backend
+    return group.st.backend if group.single else "python"
+
+
 def _timed_advance(group, limit) -> tuple[bool, float]:
     """Side-lane unit of work: advance one group to its barrier limit and
     report (progressed, seconds).  Groups share no mutable state and each
@@ -666,7 +678,7 @@ def _advance_fused(
             old_w, old_h, new_w, new_h, old_k, new_k = req
             totals, d_e = portfolio_step(
                 W, H, old_w, old_h, new_w, new_h,
-                modes=st.modes0, backend=st.backend, interpret=st.interpret,
+                modes=st.modes0, backend=st.backend,
                 kinds=Km, old_k=old_k, new_k=new_k,
                 kind_tables=st.kt if old_k is not None else None,
                 mesh=st.mesh,
@@ -1222,6 +1234,8 @@ def pack_portfolio(
             scheduler=scheduler,
             n_shards=n_shards,
             fused=bool(fuse),
+            backends={lab: _group_backend(g) for lab, g in zip(labels, groups)},
+            interpret=any(pallas_interpret(_group_backend(g)) for g in groups),
             strides=dict(zip(labels, strides)),
             barrier_seconds=barrier_seconds,
             group_seconds=group_seconds,

@@ -75,9 +75,10 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.kernels.platform import pallas_interpret
+
 from .ga import (
     BACKENDS,
-    _default_jax_backend,
     apply_swap_moves,
     buffer_swap,
     kind_reassign,
@@ -225,7 +226,7 @@ class SimulatedAnnealingPacker:
         # swap_moves entries): host numpy beats per-step device dispatch
         from repro.kernels.binpack_sa_step.ops import resolve_auto
 
-        return resolve_auto()[0]
+        return resolve_auto()
 
     def _perturb(self, sol: Solution, rng: np.random.Generator) -> Solution:
         if self.perturbation == "nfd":
@@ -406,7 +407,6 @@ class SimulatedAnnealingPacker:
         st = _SingleChainRun()
         st.prob = prob
         st.backend = backend
-        st.interpret = backend == "pallas" and _default_jax_backend() != "tpu"
         st.rng = rng if rng is not None else np.random.default_rng(self.seed)
         st.t_start = time.perf_counter()
         if init is not None and not isinstance(init, Solution):
@@ -465,7 +465,7 @@ class SimulatedAnnealingPacker:
         )
         prob, sol, rng = st.prob, st.sol, st.rng
         hetero, lam, pk, kt, modes0 = st.hetero, st.lam, st.pk, st.kt, st.modes0
-        backend, interpret = st.backend, st.interpret
+        backend = st.backend
         chain_w, chain_h, chain_k = st.chain_w, st.chain_h, st.chain_k
         old_w, old_h = st.old_w, st.old_h
         new_w, new_h = st.new_w, st.new_h
@@ -508,7 +508,7 @@ class SimulatedAnnealingPacker:
                 d_cost = int(
                     sa_step_deltas(
                         old_w, old_h, new_w, new_h, backend=backend,
-                        interpret=interpret, old_k=old_k, new_k=new_k,
+                        old_k=old_k, new_k=new_k,
                         kind_tables=kt,
                     )[0]
                 )
@@ -533,7 +533,7 @@ class SimulatedAnnealingPacker:
                 d_cost = int(
                     sa_step_deltas(
                         old_w, old_h, new_w, new_h, modes=modes0,
-                        backend=backend, interpret=interpret,
+                        backend=backend,
                     )[0]
                 )
                 d_e = d_cost
@@ -702,7 +702,6 @@ class SimulatedAnnealingPacker:
         st.probs = list(probs)
         st.rngs = list(rngs)
         st.backend = backend
-        st.interpret = backend == "pallas" and _default_jax_backend() != "tpu"
         batch = st.batch = encode_problem_batch(probs)
         hetero = st.hetero = batch.n_kinds > 1
         lam = self.inventory_penalty
@@ -796,12 +795,12 @@ class SimulatedAnnealingPacker:
         if old_k is not None:
             return sa_step_deltas(
                 old_w, old_h, new_w, new_h, backend=st.backend,
-                interpret=st.interpret, old_k=old_k, new_k=new_k,
+                old_k=old_k, new_k=new_k,
                 kind_tables=st.kt, mesh=st.mesh,
             )
         return sa_step_deltas(
             old_w, old_h, new_w, new_h, modes=st.modes0,
-            backend=st.backend, interpret=st.interpret, mesh=st.mesh,
+            backend=st.backend, mesh=st.mesh,
         )
 
     def _block_run(self, st: _BlockState, it_limit: int | None = None) -> None:
@@ -820,13 +819,13 @@ class SimulatedAnnealingPacker:
             if hetero:
                 d_e = sa_step_deltas(
                     old_w, old_h, new_w, new_h, backend=st.backend,
-                    interpret=st.interpret, old_k=old_k, new_k=new_k,
+                    old_k=old_k, new_k=new_k,
                     kind_tables=st.kt, mesh=st.mesh,
                 )
             else:
                 d_e = sa_step_deltas(
                     old_w, old_h, new_w, new_h, modes=st.modes0,
-                    backend=st.backend, interpret=st.interpret, mesh=st.mesh,
+                    backend=st.backend, mesh=st.mesh,
                 )
             try:
                 req = gen.send(d_e)
@@ -1254,6 +1253,7 @@ class SimulatedAnnealingPacker:
             p_adm_h=self.p_adm_h,
             seed=self.seed,
             backend=backend,
+            interpret=pallas_interpret(backend),
             n_chains=self.n_chains if backend != "legacy" else 1,
         )
         if uphill is not None:

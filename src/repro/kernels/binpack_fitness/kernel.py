@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
+
 POP_TILE = 8  # population rows per program (sublane tile for int32)
 
 
@@ -59,7 +61,7 @@ def binpack_fitness_pallas(
     widths: jax.Array,  # (P, NB) int32
     heights: jax.Array,  # (P, NB) int32
     modes: tuple[tuple[int, int], ...],
-    interpret: bool = True,  # CPU host: validate via interpreter
+    interpret: bool | None = None,  # None: compiled on TPU, else interpreted
 ) -> jax.Array:
     p, nb = widths.shape
     pad_p = (-p) % POP_TILE
@@ -77,7 +79,7 @@ def binpack_fitness_pallas(
         ],
         out_specs=pl.BlockSpec((POP_TILE, nbp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pp, nbp), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(widths, heights)
     return out[:p, :nb]
 
@@ -88,7 +90,7 @@ def binpack_fitness_kinds_pallas(
     heights: jax.Array,  # (P, NB) int32
     kinds: jax.Array,  # (P, NB) int32 RAM-kind indices
     kind_tables: tuple[tuple[int, tuple[tuple[int, int], ...]], ...],
-    interpret: bool = True,  # CPU host: validate via interpreter
+    interpret: bool | None = None,  # None: compiled on TPU, else interpreted
 ) -> jax.Array:
     p, nb = widths.shape
     pad_p = (-p) % POP_TILE
@@ -105,6 +107,6 @@ def binpack_fitness_kinds_pallas(
         in_specs=[pl.BlockSpec((POP_TILE, nbp), lambda i: (i, 0))] * 3,
         out_specs=pl.BlockSpec((POP_TILE, nbp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pp, nbp), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(widths, heights, kinds)
     return out[:p, :nb]

@@ -2,8 +2,10 @@
 
 This is the GA's generation-evaluation primitive: rows are individuals,
 columns are bins, entries are the bin geometry; empty (padded) slots carry
-``width == 0`` and cost nothing.  ``backend="auto"`` picks the Pallas kernel
-when a TPU is attached and the pure-jnp reference otherwise.
+``width == 0`` and cost nothing.  ``backend="auto"`` (the default) picks the
+Pallas kernel when a TPU is attached and the pure-jnp reference otherwise.
+The Pallas kernel compiles on a TPU and is interpreted elsewhere
+(``kernels.platform``).
 
 Heterogeneous OCM problems pass a parallel ``kinds`` matrix plus the
 problem's precomputed ``kind_tables`` (``((weight, modes), ...)`` per RAM
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.problem import BRAM18_MODES
+from repro.kernels.platform import on_tpu
 
 from .kernel import binpack_fitness_kinds_pallas, binpack_fitness_pallas
 from .ref import binpack_fitness_kinds_ref, binpack_fitness_ref
@@ -38,8 +41,7 @@ def population_costs(
     widths,
     heights,
     modes=BRAM18_MODES,
-    backend: str = "pallas",
-    interpret=True,
+    backend: str = "auto",
     kinds=None,
     kind_tables=None,
     mesh=None,
@@ -72,21 +74,16 @@ def population_costs(
             heights.reshape(np_ * p_, nb_),
             modes=modes,
             backend=backend,
-            interpret=interpret,
             kinds=None if kinds is None else jnp.asarray(kinds).reshape(np_ * p_, nb_),
             kind_tables=kind_tables,
             mesh=mesh,
         )
         return totals.reshape(np_, p_)
     if backend == "auto":
-        if jax.default_backend() == "tpu":
-            backend, interpret = "pallas", False
-        else:
-            backend = "ref"
+        backend = "pallas" if on_tpu() else "ref"
     if mesh is not None:
         return _population_costs_sharded(
-            widths, heights, modes, backend, interpret, kinds, kind_tables,
-            mesh,
+            widths, heights, modes, backend, kinds, kind_tables, mesh,
         )
     if kinds is not None:
         if kind_tables is None:
@@ -94,7 +91,7 @@ def population_costs(
         kind_tables = tuple((int(w), tuple(m)) for w, m in kind_tables)
         if backend == "pallas":
             per_bin = binpack_fitness_kinds_pallas(
-                widths, heights, kinds, kind_tables, interpret
+                widths, heights, kinds, kind_tables
             )
             return jnp.sum(per_bin, axis=1)
         if backend != "ref":
@@ -103,7 +100,7 @@ def population_costs(
             )
         return _ref_totals_kinds(widths, heights, kinds, kind_tables)
     if backend == "pallas":
-        per_bin = binpack_fitness_pallas(widths, heights, tuple(modes), interpret)
+        per_bin = binpack_fitness_pallas(widths, heights, tuple(modes))
         return jnp.sum(per_bin, axis=1)
     if backend != "ref":
         raise ValueError(f"unknown backend {backend!r}; options: auto, pallas, ref")
@@ -114,7 +111,7 @@ _SHARD_CACHE: dict = {}
 
 
 def _population_costs_sharded(
-    widths, heights, modes, backend, interpret, kinds, kind_tables, mesh
+    widths, heights, modes, backend, kinds, kind_tables, mesh
 ):
     """Row-sharded evaluation over the ``("prob",)`` mesh (PR 8)."""
     from repro.kernels.probshard import mesh_size, pad_rows, row_shard
@@ -125,25 +122,23 @@ def _population_costs_sharded(
         if kind_tables is None:
             raise ValueError("kinds requires kind_tables")
         kind_tables = tuple((int(w), tuple(m)) for w, m in kind_tables)
-        key = (mesh, backend, interpret, kind_tables)
+        key = (mesh, backend, kind_tables)
     else:
         modes = tuple(modes)
-        key = (mesh, backend, interpret, modes)
+        key = (mesh, backend, modes)
     fn = _SHARD_CACHE.get(key)
     if fn is None:
         if backend == "pallas":
             if hetero:
                 def body(w, h, kk):
                     return jnp.sum(
-                        binpack_fitness_kinds_pallas(
-                            w, h, kk, kind_tables, interpret
-                        ),
+                        binpack_fitness_kinds_pallas(w, h, kk, kind_tables),
                         axis=1,
                     )
             else:
                 def body(w, h):
                     return jnp.sum(
-                        binpack_fitness_pallas(w, h, modes, interpret), axis=1
+                        binpack_fitness_pallas(w, h, modes), axis=1
                     )
         elif backend == "ref":
             if hetero:

@@ -3,7 +3,8 @@
 Composes the two existing Pallas kernels (``binpack_fitness``'s population
 evaluator and ``binpack_sa_step``'s delta-cost step) under one jit, so a TPU
 run launches ONE compiled program per fused barrier segment; off-TPU the
-interpreter path validates the exact same composition.  Both kernels are
+interpreter path validates the exact same composition (``interpret=None``
+decides from the platform, ``kernels.platform``).  Both kernels are
 exact-integer, so the fused results stay bit-identical to the separate
 dispatches (pinned in ``tests/test_kernels.py``).
 """
@@ -26,7 +27,7 @@ from repro.kernels.binpack_sa_step.kernel import (
 
 @functools.partial(jax.jit, static_argnames=("modes", "interpret"))
 def portfolio_step_pallas(
-    W, H, old_w, old_h, new_w, new_h, modes, interpret
+    W, H, old_w, old_h, new_w, new_h, modes, interpret=None
 ):
     nb = W.shape[-1]
     per_bin = binpack_fitness_pallas(
@@ -39,7 +40,8 @@ def portfolio_step_pallas(
 
 @functools.partial(jax.jit, static_argnames=("kind_tables", "interpret"))
 def portfolio_step_kinds_pallas(
-    W, H, Km, old_w, old_h, old_k, new_w, new_h, new_k, kind_tables, interpret
+    W, H, Km, old_w, old_h, old_k, new_w, new_h, new_k, kind_tables,
+    interpret=None,
 ):
     nb = W.shape[-1]
     per_bin = binpack_fitness_kinds_pallas(

@@ -8,7 +8,8 @@ touched-bin delta costs (the ``binpack_sa_step`` contract).  Backends:
 * ``"python"`` — vectorized numpy for both halves; no JAX on the hot path.
 * ``"ref"`` — ONE jit'd pure-jnp program computing both halves.
 * ``"pallas"`` — the two Pallas kernels composed under one jit (a single
-  compiled program per fused segment on TPU; interpreter-validated off-TPU).
+  compiled program per fused segment on TPU, interpreted elsewhere —
+  ``kernels.platform``).
 * ``"auto"`` — ``pallas`` when a TPU is attached, else ``ref``.
 
 All backends use exact integer arithmetic: the returned totals are
@@ -22,6 +23,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.problem import BRAM18_MODES
+from repro.kernels.platform import on_tpu
 from repro.kernels.binpack_sa_step.ops import (
     _bin_costs_kinds_numpy,
     _bin_costs_numpy,
@@ -39,7 +41,6 @@ def portfolio_step(
     new_h,
     modes=BRAM18_MODES,
     backend: str = "auto",
-    interpret: bool = True,
     kinds=None,
     old_k=None,
     new_k=None,
@@ -69,14 +70,14 @@ def portfolio_step(
             "portfolio's islands share one problem) or not at all"
         )
     if backend == "auto":
-        backend, interpret = resolve_auto()
+        backend = resolve_auto()
     if hetero:
         kind_tables = tuple((int(w), tuple(m)) for w, m in kind_tables)
     else:
         modes = tuple(modes)
     if mesh is not None and backend in ("ref", "pallas"):
         return _portfolio_step_sharded(
-            W, H, old_w, old_h, new_w, new_h, modes, backend, interpret,
+            W, H, old_w, old_h, new_w, new_h, modes, backend,
             kinds, old_k, new_k, kind_tables, mesh,
         )
     if backend == "python":
@@ -116,7 +117,7 @@ def portfolio_step(
                 jnp.asarray(W), jnp.asarray(H), jnp.asarray(kinds),
                 jnp.asarray(old_w), jnp.asarray(old_h), jnp.asarray(old_k),
                 jnp.asarray(new_w), jnp.asarray(new_h), jnp.asarray(new_k),
-                kind_tables, interpret,
+                kind_tables,
             )
         else:
             from .kernel import portfolio_step_pallas
@@ -124,7 +125,7 @@ def portfolio_step(
             totals, deltas = portfolio_step_pallas(
                 jnp.asarray(W), jnp.asarray(H),
                 jnp.asarray(old_w), jnp.asarray(old_h),
-                jnp.asarray(new_w), jnp.asarray(new_h), modes, interpret,
+                jnp.asarray(new_w), jnp.asarray(new_h), modes,
             )
     else:
         raise ValueError(f"unknown backend {backend!r}; options: {BACKENDS}")
@@ -138,7 +139,7 @@ _SHARD_CACHE: dict = {}
 
 
 def _portfolio_step_sharded(
-    W, H, old_w, old_h, new_w, new_h, modes, backend, interpret,
+    W, H, old_w, old_h, new_w, new_h, modes, backend,
     kinds, old_k, new_k, kind_tables, mesh,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Row-sharded fused step over the ``("prob",)`` mesh (PR 8).
@@ -154,9 +155,9 @@ def _portfolio_step_sharded(
     k = mesh_size(mesh)
     hetero = kind_tables is not None
     if hetero:
-        key = (mesh, backend, interpret, kind_tables)
+        key = (mesh, backend, kind_tables)
     else:
-        key = (mesh, backend, interpret, modes)
+        key = (mesh, backend, modes)
     fn = _SHARD_CACHE.get(key)
     if fn is None:
         if backend == "ref":
@@ -180,12 +181,11 @@ def _portfolio_step_sharded(
                 def body(w, h, kk, ow, oh, ok, nw, nh, nk):
                     return portfolio_step_kinds_pallas(
                         w, h, kk, ow, oh, ok, nw, nh, nk, kind_tables,
-                        interpret,
                     )
             else:
                 def body(w, h, ow, oh, nw, nh):
                     return portfolio_step_pallas(
-                        w, h, ow, oh, nw, nh, modes, interpret
+                        w, h, ow, oh, nw, nh, modes
                     )
         fn = _SHARD_CACHE[key] = row_shard(mesh, body, n_outputs=2)
     pop = (W, H) + ((kinds,) if hetero else ())
@@ -245,17 +245,10 @@ def _jit_ref_kinds():
     return _REF_KINDS_JIT
 
 
-def resolve_auto() -> tuple[str, bool]:
+def resolve_auto() -> str:
     """The fused-step "auto" policy: the Pallas composition on a real TPU,
     the jit'd reference elsewhere.  (The portfolio only routes barriers
     through the fused path when BOTH engine backends are jax-resolved, so
     on a CPU host — where SA auto-resolves to host numpy — fused dispatch
     stays off and this policy never demotes the hot path.)"""
-    try:
-        import jax
-
-        if jax.default_backend() == "tpu":
-            return "pallas", False
-    except Exception:
-        pass
-    return "ref", True
+    return "pallas" if on_tpu() else "ref"

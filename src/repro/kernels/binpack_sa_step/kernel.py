@@ -26,6 +26,7 @@ from jax.experimental import pallas as pl
 # the per-kind cost block is shared with the fitness kernel so the two
 # Pallas bodies can never drift apart arithmetically
 from repro.kernels.binpack_fitness.kernel import kind_cost_block
+from repro.kernels.platform import resolve_interpret
 
 CHAIN_TILE = 8  # chain rows per program (sublane tile for int32)
 
@@ -50,7 +51,7 @@ def sa_step_deltas_pallas(
     new_w: jax.Array,
     new_h: jax.Array,
     modes: tuple[tuple[int, int], ...],
-    interpret: bool = True,  # CPU host: validate via interpreter
+    interpret: bool | None = None,  # None: compiled on TPU, else interpreted
 ) -> jax.Array:
     c, t = old_w.shape
     pad_c = (-c) % CHAIN_TILE
@@ -67,7 +68,7 @@ def sa_step_deltas_pallas(
         in_specs=[pl.BlockSpec((CHAIN_TILE, tp), lambda i: (i, 0))] * 4,
         out_specs=pl.BlockSpec((CHAIN_TILE, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(old_w, old_h, new_w, new_h)
     return out[:c, 0]
 
@@ -90,7 +91,7 @@ def sa_step_deltas_kinds_pallas(
     new_h: jax.Array,
     new_k: jax.Array,
     kind_tables: tuple[tuple[int, tuple[tuple[int, int], ...]], ...],
-    interpret: bool = True,  # CPU host: validate via interpreter
+    interpret: bool | None = None,  # None: compiled on TPU, else interpreted
 ) -> jax.Array:
     """Heterogeneous fused delta step: per-slot kind lanes select the mode
     table and unit weight (same tiling as the homogeneous kernel)."""
@@ -108,6 +109,6 @@ def sa_step_deltas_kinds_pallas(
         in_specs=[pl.BlockSpec((CHAIN_TILE, tp), lambda i: (i, 0))] * 6,
         out_specs=pl.BlockSpec((CHAIN_TILE, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return out[:c, 0]

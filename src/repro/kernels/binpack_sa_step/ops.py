@@ -9,7 +9,8 @@ single call.  Backends:
   tiny per-step shapes (T = 2 * swap_moves) this is the fastest option on a
   CPU host, where per-call device dispatch would dominate.
 * ``"ref"`` — jit'd pure-jnp oracle (one fused XLA computation per step).
-* ``"pallas"`` — the Pallas TPU kernel (interpreter-validated off-TPU).
+* ``"pallas"`` — the Pallas TPU kernel (compiled on a TPU, interpreted
+  elsewhere — ``kernels.platform``).
 * ``"auto"`` — ``pallas`` when a TPU is attached, else ``python``.
 
 All backends use exact integer arithmetic and return bit-identical deltas;
@@ -27,6 +28,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.problem import BRAM18_MODES
+from repro.kernels.platform import on_tpu
 
 BACKENDS = ("auto", "python", "ref", "pallas")
 
@@ -56,7 +58,6 @@ def sa_step_deltas(
     new_h,
     modes=BRAM18_MODES,
     backend: str = "auto",
-    interpret: bool = True,
     old_k=None,
     new_k=None,
     kind_tables=None,
@@ -85,13 +86,13 @@ def sa_step_deltas(
     ignores ``mesh``.
     """
     if backend == "auto":
-        backend, interpret = resolve_auto()
+        backend = resolve_auto()
     if np.ndim(old_w) == 3:
         np_, c_, t_ = np.shape(old_w)
         flat = lambda a: None if a is None else np.reshape(np.asarray(a), (np_ * c_, t_))  # noqa: E731
         out = sa_step_deltas(
             flat(old_w), flat(old_h), flat(new_w), flat(new_h),
-            modes=modes, backend=backend, interpret=interpret,
+            modes=modes, backend=backend,
             old_k=flat(old_k), new_k=flat(new_k), kind_tables=kind_tables,
             mesh=mesh,
         )
@@ -103,7 +104,7 @@ def sa_step_deltas(
         kind_tables = tuple((int(w), tuple(m)) for w, m in kind_tables)
     if mesh is not None and backend in ("ref", "pallas"):
         return _sa_step_deltas_sharded(
-            old_w, old_h, new_w, new_h, modes, backend, interpret,
+            old_w, old_h, new_w, new_h, modes, backend,
             old_k, new_k, kind_tables, mesh,
         )
     if backend == "python":
@@ -135,14 +136,14 @@ def sa_step_deltas(
             out = sa_step_deltas_kinds_pallas(
                 jnp.asarray(old_w), jnp.asarray(old_h), jnp.asarray(old_k),
                 jnp.asarray(new_w), jnp.asarray(new_h), jnp.asarray(new_k),
-                kind_tables, interpret,
+                kind_tables,
             )
         else:
             from .kernel import sa_step_deltas_pallas
 
             out = sa_step_deltas_pallas(
                 jnp.asarray(old_w), jnp.asarray(old_h),
-                jnp.asarray(new_w), jnp.asarray(new_h), tuple(modes), interpret,
+                jnp.asarray(new_w), jnp.asarray(new_h), tuple(modes),
             )
     else:
         raise ValueError(f"unknown backend {backend!r}; options: {BACKENDS}")
@@ -153,7 +154,7 @@ _SHARD_CACHE: dict = {}
 
 
 def _sa_step_deltas_sharded(
-    old_w, old_h, new_w, new_h, modes, backend, interpret,
+    old_w, old_h, new_w, new_h, modes, backend,
     old_k, new_k, kind_tables, mesh,
 ) -> np.ndarray:
     """Row-sharded delta evaluation over the ``("prob",)`` mesh (PR 8)."""
@@ -164,10 +165,10 @@ def _sa_step_deltas_sharded(
     k = mesh_size(mesh)
     hetero = old_k is not None
     if hetero:
-        key = (mesh, backend, interpret, kind_tables)
+        key = (mesh, backend, kind_tables)
     else:
         modes = tuple(modes)
-        key = (mesh, backend, interpret, modes)
+        key = (mesh, backend, modes)
     fn = _SHARD_CACHE.get(key)
     if fn is None:
         if backend == "ref":
@@ -190,13 +191,11 @@ def _sa_step_deltas_sharded(
             if hetero:
                 def body(ow, oh, ok, nw, nh, nk):
                     return sa_step_deltas_kinds_pallas(
-                        ow, oh, ok, nw, nh, nk, kind_tables, interpret
+                        ow, oh, ok, nw, nh, nk, kind_tables
                     )
             else:
                 def body(ow, oh, nw, nh):
-                    return sa_step_deltas_pallas(
-                        ow, oh, nw, nh, modes, interpret
-                    )
+                    return sa_step_deltas_pallas(ow, oh, nw, nh, modes)
         fn = _SHARD_CACHE[key] = row_shard(mesh, body)
     if hetero:
         args = (old_w, old_h, old_k, new_w, new_h, new_k)
@@ -255,15 +254,8 @@ def _jit_ref_kinds():
     return _REF_KINDS_JIT
 
 
-def resolve_auto() -> tuple[str, bool]:
-    """The SA "auto" policy: (backend, interpret) — the Pallas kernel on a
-    real TPU, host numpy everywhere else (per-step shapes are too small for
-    CPU device dispatch to pay off)."""
-    try:
-        import jax
-
-        if jax.default_backend() == "tpu":
-            return "pallas", False
-    except Exception:
-        pass
-    return "python", True
+def resolve_auto() -> str:
+    """The SA "auto" policy: the Pallas kernel on a real TPU, host numpy
+    everywhere else (per-step shapes are too small for CPU device dispatch
+    to pay off)."""
+    return "pallas" if on_tpu() else "python"

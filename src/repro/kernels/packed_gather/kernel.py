@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
+
 ROW_TILE = 8  # fp32 sublane tile
 
 
@@ -38,7 +40,7 @@ def packed_gather_matvec(
     bank: jax.Array,  # (R, C) f32, R % 8 == 0, C % 128 == 0
     x: jax.Array,  # (N, C) f32 — one activation vector per logical buffer
     seg: jax.Array,  # (R,) int32 segment ids in [0, N)
-    interpret: bool = True,
+    interpret: bool | None = None,  # None: compiled on TPU, else interpreted
 ) -> jax.Array:
     r, c = bank.shape
     n = x.shape[0]
@@ -53,6 +55,6 @@ def packed_gather_matvec(
         ],
         out_specs=pl.BlockSpec((ROW_TILE, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 1), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(bank, x, seg2)
     return out[:, 0]

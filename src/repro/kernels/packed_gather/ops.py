@@ -7,9 +7,9 @@ from .kernel import packed_gather_matvec
 from .ref import packed_gather_ref
 
 
-def bank_matvec(bank, x, seg, backend: str = "pallas", interpret: bool = True):
+def bank_matvec(bank, x, seg, backend: str = "pallas"):
     if backend == "pallas":
-        return packed_gather_matvec(bank, x, seg, interpret=interpret)
+        return packed_gather_matvec(bank, x, seg)
     return packed_gather_ref(bank, x, seg)
 
 

@@ -72,7 +72,6 @@ def row_shard(mesh, fn, n_outputs: int = 1):
     function per static key so jit compiles once per configuration.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.rules import prob_axis_spec
@@ -83,12 +82,12 @@ def row_shard(mesh, fn, n_outputs: int = 1):
             out_specs = P("prob")
         else:
             out_specs = tuple(P("prob") for _ in range(n_outputs))
-        # check_rep=False: jax has no replication rule for pallas_call, and
-        # nothing here relies on replication checking (every operand and
-        # output is explicitly row-sharded or replicated).
-        body = shard_map(
+        # check_vma=False: jax has no varying-axes rule for pallas_call,
+        # and nothing here relies on that check (every operand and output
+        # is explicitly row-sharded or replicated).
+        body = jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return body(*arrays)
 

@@ -16,6 +16,19 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which slicing or
+    reshaping a sharded output (``out[:n]`` after row padding, the fleet
+    ops' ``(NP*P,) -> (NP, P)`` reshape) is a sharding type error.  With
+    ``Auto`` axes the compiler propagates shardings through those ops."""
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -30,12 +43,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             "(dryrun.py sets this automatically), or use make_host_mesh() / "
             "make_sweep_mesh(n) for CPU runs"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return auto_mesh(shape, axes, devices[:n])
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """1-device mesh for CPU smoke tests and examples."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    return auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def make_sweep_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
@@ -59,4 +72,4 @@ def make_sweep_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n_devices} "
             "for host-platform sharding"
         )
-    return jax.make_mesh((n_devices,), ("prob",), devices=devices[:n_devices])
+    return auto_mesh((n_devices,), ("prob",), devices[:n_devices])

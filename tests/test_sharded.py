@@ -184,6 +184,31 @@ def test_sweep_sa_mesh_bit_identical():
     assert _record(pinned) == _record(base)
 
 
+def test_sweep_pinned_shards_spread_over_devices(monkeypatch):
+    """``n_shards > 1`` with a mesh pins each sub-fleet's kernel calls to
+    its own device through the thread-local ``jax.default_device``: the
+    kernel outputs land on every mesh device, not all on device 0."""
+    mesh = _mesh_or_skip(2)
+    from repro.kernels.binpack_sa_step import kernel
+
+    k = mesh.shape["prob"]
+    seen: set = set()
+    kernel_fn = kernel.sa_step_deltas_pallas
+
+    def spy(*args):
+        out = kernel_fn(*args)
+        seen.update(out.devices())
+        return out
+
+    probs = [_problem(s) for s in range(101, 101 + k)]
+    kw = dict(_KW, backend="pallas", max_iterations=12, n_chains=2)
+    base = pack_sweep(probs, "sa-s", seed=5, **kw)
+    monkeypatch.setattr(kernel, "sa_step_deltas_pallas", spy)
+    pinned = pack_sweep(probs, "sa-s", seed=5, mesh=mesh, n_shards=k, **kw)
+    assert _record(pinned) == _record(base)
+    assert seen == set(mesh.devices.flat)
+
+
 def test_sweep_ga_mesh_bit_identical():
     mesh = _mesh_or_skip(2)
     probs = [_problem(s) for s in (51, 52, 53)]
