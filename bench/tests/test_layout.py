@@ -1,0 +1,112 @@
+"""BENCHMARK.json resolves to its files by name, keeps to the shape the
+check reads, and takes a new cell from data files alone."""
+from __future__ import annotations
+
+import json
+import re
+import shutil
+from pathlib import Path
+
+import pytest
+
+from bench import harness
+from bench.tests.helpers import run_tiny
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads",
+                          "end_to_end", "per_layer"}
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert all((ROOT / p).is_dir() for p in BENCH["paths"])
+    assert (ROOT / BENCH["command"][1]).is_file()
+    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    names += CELLS + [c["name"] for c in BENCH["configs"]]
+    assert len(names) == len(set(names))
+    assert all(NAME.match(n) for n in names)
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e
+    for m in BENCH["end_to_end"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        assert 0.01 <= m["bound"] <= 0.25
+        assert m["source"] in ("host_clock", "device_trace")
+    for m in BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["moves"] in e2e
+        assert set(m) <= {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_resolves_by_name(cell):
+    c = harness.load_cell(ROOT, cell)
+    assert c.chips == 1
+    assert c.traffic["entry"] in harness.LOOPS
+    assert {m["name"] for m in c.end_to_end} >= {"setup_s"} and len(c.end_to_end) >= 2
+    assert c.per_layer
+    for m in c.per_layer:
+        assert callable(harness.metric_reader(ROOT, m["name"]))
+        assert m["moves"] in {e["name"] for e in c.end_to_end}
+    probs = harness.reference_problems(c.config)
+    assert len(probs) == len(c.config["problems"])
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
+def test_config_file_names_its_reductions(entry):
+    cfg = json.loads((ROOT / entry["file"]).read_text())
+    assert set(entry["reduced"]) <= set(cfg)
+    assert set(entry["reduced"]) == set(cfg["reduced_from_source"])
+    sizes = {p.name: p.n for p in harness.reference_problems(cfg)}
+    if "RN152-W1A2@U50" in sizes:  # Table 1 with the documented RN152 scaling
+        assert sizes["RN152-W1A2@U50"] == 2253
+
+
+SERVE_MIX = {"entry": "serve", "loop": "open", "rate_hz": 2.0, "zipf_a": 1.2,
+             "revisit": 0.5, "service": {"max_batch": 8, "max_wait_ms": 5.0},
+             "check_sample": 4}
+OCCUPANCY = '''def read(run):
+    if not run.stats or not run.stats["batches"]:
+        return None
+    return run.stats["batch_occupancy"]["mean"]
+'''
+
+
+@pytest.mark.parametrize("entry", ["pack", "serve"])
+def test_a_cell_made_of_data_files_runs_without_a_harness_edit(tmp_path, entry):
+    """A later PR adds a cell with a traffic file, metric readers and
+    entries only: a closed loop of single solves, or an open loop of
+    service requests."""
+    for sub in ("configs", "traffic", "metrics"):
+        shutil.copytree(ROOT / "bench" / sub, tmp_path / "bench" / sub)
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    name = f"table1-dse.{entry}"
+    if entry == "pack":
+        mix = json.loads((ROOT / "bench" / "traffic" / "single-pack-sa.json").read_text())
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if m["name"].endswith("solve") or m["name"] == "solve_s":
+                m["workloads"].append(name)
+        want = {"solve_s", "setup_s"}
+    else:
+        mix = SERVE_MIX
+        (tmp_path / "bench" / "metrics" / "serve_batch_occupancy.py").write_text(OCCUPANCY)
+        bench["end_to_end"].append({"name": "request_p95_ms", "unit": "ms", "better": "lower",
+                                    "bound": 0.25, "source": "host_clock",
+                                    "workloads": [name]})
+        bench["per_layer"].append({"name": "serve_batch_occupancy", "unit": "requests/batch",
+                                   "better": "higher", "source": "program_counter",
+                                   "layer": "service", "moves": "request_p95_ms",
+                                   "workloads": [name]})
+        want = {"request_p95_ms", "setup_s"}
+    (tmp_path / "bench" / "traffic" / f"{entry}-mix.json").write_text(json.dumps(mix))
+    bench["workloads"].append({"name": name, "config": "table1-zu7ev-u50",
+                               "traffic": f"{entry}-mix", "chips": 1, "why": "a test cell"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    line = run_tiny(tmp_path, name, seed=7, seconds=0.5)
+    assert line["correct"] is True
+    assert set(line["metrics"]) == want
+    if entry == "serve":
+        traced = run_tiny(tmp_path, name, seed=8, seconds=0.5, trace=True)
+        assert set(traced["metrics"]) == {"serve_batch_occupancy"}
