@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.spans import span
+
 from .problem import PackingProblem, Solution, greedy_assign_kinds
 
 
@@ -181,4 +183,5 @@ def nfd_from_scratch(
     )
     # heterogeneous devices: start from an inventory-feasible kind lane
     # (deterministic, no RNG draws; no-op on single-kind problems)
-    return greedy_assign_kinds(sol)
+    with span("repro.nfd.kinds"):
+        return greedy_assign_kinds(sol)

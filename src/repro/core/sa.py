@@ -76,6 +76,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.kernels.platform import pallas_interpret
+from repro.spans import span
 
 from .ga import (
     BACKENDS,
@@ -692,98 +693,100 @@ class SimulatedAnnealingPacker:
         ``mesh`` (a ``("prob",)`` sweep mesh) row-shards the delta kernel on
         jax backends — a start-derived constant, never serialized (resume
         may restore onto a different mesh/shard count, DESIGN.md §14)."""
-        st = _BlockState()
-        st.mesh = mesh if backend in ("ref", "pallas") else None
-        n_probs = st.n_probs = len(probs)
-        n_chains = self.n_chains
-        n_rows = st.n_rows = n_probs * n_chains
-        st.n_moves = max(self.swap_moves, 1)
-        width = 2 * st.n_moves
-        st.probs = list(probs)
-        st.rngs = list(rngs)
-        st.backend = backend
-        batch = st.batch = encode_problem_batch(probs)
-        hetero = st.hetero = batch.n_kinds > 1
-        lam = self.inventory_penalty
-        st.kt = batch.kind_tables if hetero else None
-        st.modes0 = batch.kind_tables[0][1]  # == BRAM18_MODES on defaults
-        st.n_kinds = batch.n_kinds
-        st.cap_max = batch.cap_max
-        st.any_bounded = bool((batch.kind_counts >= 0).any())
-        st.t_start = time.perf_counter()
+        with span("repro.sa.start"):
+            st = _BlockState()
+            st.mesh = mesh if backend in ("ref", "pallas") else None
+            n_probs = st.n_probs = len(probs)
+            n_chains = self.n_chains
+            n_rows = st.n_rows = n_probs * n_chains
+            st.n_moves = max(self.swap_moves, 1)
+            width = 2 * st.n_moves
+            st.probs = list(probs)
+            st.rngs = list(rngs)
+            st.backend = backend
+            batch = st.batch = encode_problem_batch(probs)
+            hetero = st.hetero = batch.n_kinds > 1
+            lam = self.inventory_penalty
+            st.kt = batch.kind_tables if hetero else None
+            st.modes0 = batch.kind_tables[0][1]  # == BRAM18_MODES on defaults
+            st.n_kinds = batch.n_kinds
+            st.cap_max = batch.cap_max
+            st.any_bounded = bool((batch.kind_counts >= 0).any())
+            st.t_start = time.perf_counter()
 
-        # --- per-problem chain init: warm starts first, fresh NFD for the rest
-        sols: list[Solution] = []
-        for j, prob in enumerate(probs):
-            mine = [s.copy() for s in inits[j][:n_chains]]
-            mine += [
-                nfd_from_scratch(
-                    prob,
-                    rngs[j],
-                    p_adm_w=self.p_adm_w,
-                    p_adm_h=self.p_adm_h,
-                    intra_layer=self.intra_layer,
-                    sort_by_width=(c % 2 == 1),
-                )
-                for c in range(len(mine), n_chains)
+            # --- per-problem chain init: warm starts first, fresh NFD for the rest
+            sols: list[Solution] = []
+            with span("repro.sa.seed"):
+                for j, prob in enumerate(probs):
+                    mine = [s.copy() for s in inits[j][:n_chains]]
+                    mine += [
+                        nfd_from_scratch(
+                            prob,
+                            rngs[j],
+                            p_adm_w=self.p_adm_w,
+                            p_adm_h=self.p_adm_h,
+                            intra_layer=self.intra_layer,
+                            sort_by_width=(c % 2 == 1),
+                        )
+                        for c in range(len(mine), n_chains)
+                    ]
+                    sols.extend(mine)
+            st.items, st.counts = encode_chain_items(sols, st.cap_max, n_slots=n_slots)
+            st.bw, st.bh, st.live = encode_chain_geometry(sols, st.items.shape[1])
+            st.costs = np.asarray([s.cost() for s in sols], dtype=np.int64)
+
+            st.pi = np.repeat(np.arange(n_probs), n_chains)  # row -> problem index
+            st.caps_r = np.repeat(batch.max_items, n_chains)  # per-row cardinality
+            # buffer lookup tables with a zero/empty sentinel in the last column;
+            # a single-problem fleet keeps the flat 1-D tables (PR 2's hot path)
+            wext, dext, lext = batch.ext_tables()
+            if n_probs == 1:
+                st.wtab, st.dtab, st.ltab = wext[0], dext[0], lext[0]
+            else:
+                st.wtab, st.dtab, st.ltab = wext, dext, lext
+            st.sentinel = st.wtab.shape[-1] - 1
+
+            if hetero:
+                # per-chain RAM-kind lane + per-kind primitive usage (R, K)
+                st.bk = encode_chain_kinds(sols, st.items.shape[1])
+                st.UK = np.stack([s.used_primitives() for s in sols])
+                st.pcosts = st.costs + lam * batch.overflow_rows(st.UK, st.pi)
+            else:
+                st.bk = None
+                st.UK = None
+                st.pcosts = st.costs
+
+            st.best_pcosts = st.pcosts.copy()  # per-chain best (drives patience)
+            st.poff = np.arange(n_probs) * n_chains
+            gis = st.pcosts.reshape(n_probs, n_chains).argmin(axis=1) + st.poff
+            st.gbest_pcost = st.pcosts[gis].copy()  # per-problem global best
+            st.gbest_cost = st.costs[gis].copy()
+            st.g_items = st.items[gis].copy()
+            st.g_counts = st.counts[gis].copy()
+            st.g_live = st.live[gis].copy()
+            st.g_kinds = st.bk[gis].copy() if hetero else None
+            st.g_UK = st.UK[gis].copy() if hetero else None
+            # hetero traces record the penalized cost (monotone); raw otherwise
+            now = time.perf_counter() - st.t_start
+            st.traces = [
+                [(now, float(st.gbest_pcost[j]) if hetero else int(st.gbest_cost[j]))]
+                for j in range(n_probs)
             ]
-            sols.extend(mine)
-        st.items, st.counts = encode_chain_items(sols, st.cap_max, n_slots=n_slots)
-        st.bw, st.bh, st.live = encode_chain_geometry(sols, st.items.shape[1])
-        st.costs = np.asarray([s.cost() for s in sols], dtype=np.int64)
-
-        st.pi = np.repeat(np.arange(n_probs), n_chains)  # row -> problem index
-        st.caps_r = np.repeat(batch.max_items, n_chains)  # per-row cardinality
-        # buffer lookup tables with a zero/empty sentinel in the last column;
-        # a single-problem fleet keeps the flat 1-D tables (PR 2's hot path)
-        wext, dext, lext = batch.ext_tables()
-        if n_probs == 1:
-            st.wtab, st.dtab, st.ltab = wext[0], dext[0], lext[0]
-        else:
-            st.wtab, st.dtab, st.ltab = wext, dext, lext
-        st.sentinel = st.wtab.shape[-1] - 1
-
-        if hetero:
-            # per-chain RAM-kind lane + per-kind primitive usage (R, K)
-            st.bk = encode_chain_kinds(sols, st.items.shape[1])
-            st.UK = np.stack([s.used_primitives() for s in sols])
-            st.pcosts = st.costs + lam * batch.overflow_rows(st.UK, st.pi)
-        else:
-            st.bk = None
-            st.UK = None
-            st.pcosts = st.costs
-
-        st.best_pcosts = st.pcosts.copy()  # per-chain best (drives patience)
-        st.poff = np.arange(n_probs) * n_chains
-        gis = st.pcosts.reshape(n_probs, n_chains).argmin(axis=1) + st.poff
-        st.gbest_pcost = st.pcosts[gis].copy()  # per-problem global best
-        st.gbest_cost = st.costs[gis].copy()
-        st.g_items = st.items[gis].copy()
-        st.g_counts = st.counts[gis].copy()
-        st.g_live = st.live[gis].copy()
-        st.g_kinds = st.bk[gis].copy() if hetero else None
-        st.g_UK = st.UK[gis].copy() if hetero else None
-        # hetero traces record the penalized cost (monotone); raw otherwise
-        now = time.perf_counter() - st.t_start
-        st.traces = [
-            [(now, float(st.gbest_pcost[j]) if hetero else int(st.gbest_cost[j]))]
-            for j in range(n_probs)
-        ]
-        st.t0s = np.tile(self._chain_t0s(), n_probs)
-        st.ri = np.arange(n_rows)
-        st.stale = np.zeros(n_rows, dtype=np.int64)
-        st.steps = np.zeros(n_rows, dtype=np.int64)
-        st.tslots = np.zeros((n_rows, width), dtype=np.int64)
-        st.entry_ok = np.zeros((n_rows, width), dtype=bool)
-        st.up_prop = np.zeros(n_probs, dtype=np.int64)
-        st.up_acc = np.zeros(n_probs, dtype=np.int64)
-        st.n_u = 6 if hetero else 4
-        st.u_all = np.zeros((st.n_moves, st.n_u, n_rows))
-        st.u_metro = np.zeros(n_rows)
-        st.it = 0
-        st.done = False
-        st.frozen = False
-        return st
+            st.t0s = np.tile(self._chain_t0s(), n_probs)
+            st.ri = np.arange(n_rows)
+            st.stale = np.zeros(n_rows, dtype=np.int64)
+            st.steps = np.zeros(n_rows, dtype=np.int64)
+            st.tslots = np.zeros((n_rows, width), dtype=np.int64)
+            st.entry_ok = np.zeros((n_rows, width), dtype=bool)
+            st.up_prop = np.zeros(n_probs, dtype=np.int64)
+            st.up_acc = np.zeros(n_probs, dtype=np.int64)
+            st.n_u = 6 if hetero else 4
+            st.u_all = np.zeros((st.n_moves, st.n_u, n_rows))
+            st.u_metro = np.zeros(n_rows)
+            st.it = 0
+            st.done = False
+            st.frozen = False
+            return st
 
     def _block_eval(self, st: _BlockState, req: tuple) -> np.ndarray:
         """Answer one `_block_gen` step request with a direct kernel call
@@ -898,123 +901,130 @@ class SimulatedAnnealingPacker:
                 st.frozen = True
                 st.done = True
                 break
-            # --- propose: each live problem draws one uniform block from its
-            # own stream (two extra rows — kind-move gate and kind pick —
-            # only on heterogeneous problems, so the single-kind block and
-            # its trajectories are untouched); frozen problems draw nothing
-            # and their rows stay masked by ``active`` below
-            for j in np.flatnonzero(act_p):
-                lo = j * n_chains
-                u_all[:, :, lo : lo + n_chains] = rngs[j].random(
-                    (n_moves, n_u, n_chains)
-                )
-            if hetero:
-                bk_new = bk.copy()  # flips land here; commit is per-chain
-            snaps = []
-            for m in range(n_moves):
-                u = u_all[m]
-                src = np.minimum((u[0] * live).astype(np.int64), live - 1)
-                dst = np.minimum((u[1] * live).astype(np.int64), live - 1)
+            with span("repro.sa.propose"):
+                # --- propose: each live problem draws one uniform block from its
+                # own stream (two extra rows — kind-move gate and kind pick —
+                # only on heterogeneous problems, so the single-kind block and
+                # its trajectories are untouched); frozen problems draw nothing
+                # and their rows stay masked by ``active`` below
+                for j in np.flatnonzero(act_p):
+                    lo = j * n_chains
+                    u_all[:, :, lo : lo + n_chains] = rngs[j].random(
+                        (n_moves, n_u, n_chains)
+                    )
                 if hetero:
-                    # a chain does a RAM-kind flip of bin ``src`` this move
-                    # instead of a buffer swap
-                    kflip = active & (u[4] < pk)
-                    idxf = np.flatnonzero(kflip)
-                    if idxf.size:
-                        shift = 1 + np.minimum(
-                            (u[5, idxf] * (n_kinds - 1)).astype(np.int64),
-                            n_kinds - 2,
+                    bk_new = bk.copy()  # flips land here; commit is per-chain
+                snaps = []
+                for m in range(n_moves):
+                    u = u_all[m]
+                    src = np.minimum((u[0] * live).astype(np.int64), live - 1)
+                    dst = np.minimum((u[1] * live).astype(np.int64), live - 1)
+                    if hetero:
+                        # a chain does a RAM-kind flip of bin ``src`` this move
+                        # instead of a buffer swap
+                        kflip = active & (u[4] < pk)
+                        idxf = np.flatnonzero(kflip)
+                        if idxf.size:
+                            shift = 1 + np.minimum(
+                                (u[5, idxf] * (n_kinds - 1)).astype(np.int64),
+                                n_kinds - 2,
+                            )
+                            bk_new[idxf, src[idxf]] = (
+                                bk_new[idxf, src[idxf]] + shift
+                            ) % n_kinds
+                    else:
+                        kflip = None
+                    ok = active & (live >= 2) & (src != dst)
+                    if hetero:
+                        ok &= ~kflip
+                    cnt_s = counts[ri, src]
+                    ok &= cnt_s > 0
+                    item_k = np.minimum(
+                        (u[2] * cnt_s).astype(np.int64), np.maximum(cnt_s - 1, 0)
+                    )
+                    item = items[ri, src, item_k]  # masked below where ~ok
+                    cnt_d = counts[ri, dst]
+                    item_safe = np.where(item >= 0, item, sentinel)
+                    if self.intra_layer:
+                        dst_first = items[ri, dst, 0]
+                        ok &= (cnt_d == 0) | (
+                            row_lookup(
+                                ltab, np.where(dst_first >= 0, dst_first, sentinel)
+                            )
+                            == row_lookup(ltab, item_safe)
                         )
-                        bk_new[idxf, src[idxf]] = (
-                            bk_new[idxf, src[idxf]] + shift
-                        ) % n_kinds
+                    full = cnt_d >= caps_r
+                    jd = np.minimum(
+                        (u[3] * cnt_d).astype(np.int64), np.maximum(cnt_d - 1, 0)
+                    )
+                    other = items[ri, dst, jd]
+                    swap = ok & full
+                    if self.intra_layer:
+                        src_first = items[ri, src, 0]
+                        swap &= (
+                            row_lookup(ltab, np.where(other >= 0, other, sentinel))
+                            == row_lookup(
+                                ltab, np.where(src_first >= 0, src_first, sentinel)
+                            )
+                        )
+                    move = ok & ~full
+                    applied = move | swap
+                    # full-row snapshots make rollback a pure scatter
+                    snaps.append(
+                        (src, dst, applied,
+                         items[ri, src], items[ri, dst], cnt_s, cnt_d)
+                    )
+                    idx = np.flatnonzero(swap)
+                    if idx.size:
+                        items[idx, dst[idx], jd[idx]] = item[idx]
+                        items[idx, src[idx], item_k[idx]] = other[idx]
+                    idx = np.flatnonzero(move)
+                    if idx.size:
+                        # remove: swap the picked slot with the last, shrink
+                        items[idx, src[idx], item_k[idx]] = items[
+                            idx, src[idx], cnt_s[idx] - 1
+                        ]
+                        items[idx, src[idx], cnt_s[idx] - 1] = -1
+                        counts[idx, src[idx]] -= 1
+                        # append
+                        items[idx, dst[idx], cnt_d[idx]] = item[idx]
+                        counts[idx, dst[idx]] += 1
+                    tslots[:, 2 * m] = src
+                    tslots[:, 2 * m + 1] = dst
+                    # a kind flip touches only the src slot (geometry unchanged,
+                    # kind lane differs); a swap touches both slots
+                    entry_ok[:, 2 * m] = applied | kflip if hetero else applied
+                    entry_ok[:, 2 * m + 1] = applied
+                # a bin touched twice contributes one delta term (first entry wins)
+                for a in range(1, width):
+                    for b in range(a):
+                        entry_ok[:, a] &= ~(
+                            entry_ok[:, b] & (tslots[:, a] == tslots[:, b])
+                        )
+                # --- fused delta-cost step over every chain of every problem
+                sel = np.where(entry_ok, tslots, 0)
+                rows = ri[:, None]
+                old_w = np.where(entry_ok, bw[rows, sel], 0).astype(np.int32)
+                old_h = np.where(entry_ok, bh[rows, sel], 0).astype(np.int32)
+                slot_items = items[rows, sel, :]  # (R, width, cap_max)
+                ids = np.where(slot_items >= 0, slot_items, sentinel)
+                new_w = np.where(
+                    entry_ok, row_lookup(wtab, ids).max(-1), 0
+                ).astype(np.int32)
+                new_h = np.where(
+                    entry_ok, row_lookup(dtab, ids).sum(-1), 0
+                ).astype(np.int32)
+                if hetero:
+                    old_k = np.where(entry_ok, bk[rows, sel], 0).astype(np.int32)
+                    new_k = np.where(entry_ok, bk_new[rows, sel], 0).astype(np.int32)
                 else:
-                    kflip = None
-                ok = active & (live >= 2) & (src != dst)
-                if hetero:
-                    ok &= ~kflip
-                cnt_s = counts[ri, src]
-                ok &= cnt_s > 0
-                item_k = np.minimum(
-                    (u[2] * cnt_s).astype(np.int64), np.maximum(cnt_s - 1, 0)
-                )
-                item = items[ri, src, item_k]  # masked below where ~ok
-                cnt_d = counts[ri, dst]
-                item_safe = np.where(item >= 0, item, sentinel)
-                if self.intra_layer:
-                    dst_first = items[ri, dst, 0]
-                    ok &= (cnt_d == 0) | (
-                        row_lookup(
-                            ltab, np.where(dst_first >= 0, dst_first, sentinel)
-                        )
-                        == row_lookup(ltab, item_safe)
-                    )
-                full = cnt_d >= caps_r
-                jd = np.minimum(
-                    (u[3] * cnt_d).astype(np.int64), np.maximum(cnt_d - 1, 0)
-                )
-                other = items[ri, dst, jd]
-                swap = ok & full
-                if self.intra_layer:
-                    src_first = items[ri, src, 0]
-                    swap &= (
-                        row_lookup(ltab, np.where(other >= 0, other, sentinel))
-                        == row_lookup(
-                            ltab, np.where(src_first >= 0, src_first, sentinel)
-                        )
-                    )
-                move = ok & ~full
-                applied = move | swap
-                # full-row snapshots make rollback a pure scatter
-                snaps.append(
-                    (src, dst, applied,
-                     items[ri, src], items[ri, dst], cnt_s, cnt_d)
-                )
-                idx = np.flatnonzero(swap)
-                if idx.size:
-                    items[idx, dst[idx], jd[idx]] = item[idx]
-                    items[idx, src[idx], item_k[idx]] = other[idx]
-                idx = np.flatnonzero(move)
-                if idx.size:
-                    # remove: swap the picked slot with the last, shrink
-                    items[idx, src[idx], item_k[idx]] = items[
-                        idx, src[idx], cnt_s[idx] - 1
-                    ]
-                    items[idx, src[idx], cnt_s[idx] - 1] = -1
-                    counts[idx, src[idx]] -= 1
-                    # append
-                    items[idx, dst[idx], cnt_d[idx]] = item[idx]
-                    counts[idx, dst[idx]] += 1
-                tslots[:, 2 * m] = src
-                tslots[:, 2 * m + 1] = dst
-                # a kind flip touches only the src slot (geometry unchanged,
-                # kind lane differs); a swap touches both slots
-                entry_ok[:, 2 * m] = applied | kflip if hetero else applied
-                entry_ok[:, 2 * m + 1] = applied
-            # a bin touched twice contributes one delta term (first entry wins)
-            for a in range(1, width):
-                for b in range(a):
-                    entry_ok[:, a] &= ~(
-                        entry_ok[:, b] & (tslots[:, a] == tslots[:, b])
-                    )
-            # --- fused delta-cost step over every chain of every problem
-            sel = np.where(entry_ok, tslots, 0)
-            rows = ri[:, None]
-            old_w = np.where(entry_ok, bw[rows, sel], 0).astype(np.int32)
-            old_h = np.where(entry_ok, bh[rows, sel], 0).astype(np.int32)
-            slot_items = items[rows, sel, :]  # (R, width, cap_max)
-            ids = np.where(slot_items >= 0, slot_items, sentinel)
-            new_w = np.where(
-                entry_ok, row_lookup(wtab, ids).max(-1), 0
-            ).astype(np.int32)
-            new_h = np.where(
-                entry_ok, row_lookup(dtab, ids).sum(-1), 0
-            ).astype(np.int32)
-            if hetero:
-                old_k = np.where(entry_ok, bk[rows, sel], 0).astype(np.int32)
-                new_k = np.where(entry_ok, bk_new[rows, sel], 0).astype(np.int32)
-                d_e = yield (old_w, old_h, new_w, new_h, old_k, new_k)
-                if any_bounded:
+                    old_k = new_k = None
+                req = (old_w, old_h, new_w, new_h, old_k, new_k)
+            # no span stays open across the yield: the consumer's dispatch
+            # is not proposal work
+            d_e = yield req
+            with span("repro.sa.accept"):
+                if hetero and any_bounded:
                     # inventory-penalty delta, vectorized over all rows: the
                     # per-kind primitive usage change of the touched slots
                     # (mode tables are fleet-shared; counts are per problem)
@@ -1030,99 +1040,96 @@ class SimulatedAnnealingPacker:
                 else:
                     dUK = None  # unbounded inventory never overflows
                     d_tot = d_e
-            else:
-                d_e = yield (old_w, old_h, new_w, new_h, None, None)
-                d_tot = d_e
-            # --- Metropolis acceptance: per-problem draws, one batched rule
-            temps = t0s / (1.0 + self.rc * it)
-            for j in np.flatnonzero(act_p):
-                lo = j * n_chains
-                u_metro[lo : lo + n_chains] = rngs[j].random(n_chains)
-            accept = metropolis_mask(d_tot, temps, u_metro) & active
-            # --- roll back rejected chains (reverse move order)
-            reject = ~accept
-            for m in range(n_moves - 1, -1, -1):
-                src, dst, applied, s_items, d_items, s_cnt, d_cnt = snaps[m]
-                idx = np.flatnonzero(reject & applied)
-                if idx.size:
-                    items[idx, dst[idx]] = d_items[idx]
-                    counts[idx, dst[idx]] = d_cnt[idx]
-                    items[idx, src[idx]] = s_items[idx]
-                    counts[idx, src[idx]] = s_cnt[idx]
-            # --- commit accepted chains
-            costs += np.where(accept, d_e, 0)
-            com = entry_ok & accept[:, None]
-            flat = np.flatnonzero(com.ravel())
-            if flat.size:
-                rr = flat // width
-                cc = tslots.ravel()[flat]
-                bw[rr, cc] = new_w.ravel()[flat]
-                bh[rr, cc] = new_h.ravel()[flat]
-            if hetero:
-                np.copyto(bk, bk_new, where=accept[:, None])
-                if dUK is not None:
-                    UK += dUK * accept[:, None]
-                pcosts = costs + lam * ovf_rows(UK)
-            else:
-                pcosts = costs
-            uphill = active & (d_tot > 0)
-            up_prop += uphill.reshape(n_probs, n_chains).sum(axis=1)
-            up_acc += (uphill & accept).reshape(n_probs, n_chains).sum(axis=1)
-            # --- per-chain best / patience bookkeeping
-            steps += active
-            improved = active & (pcosts < best_pcosts)
-            best_pcosts = np.where(improved, pcosts, best_pcosts)
-            stale = np.where(improved, 0, np.where(active, stale + 1, stale))
-            # --- per-problem global-best tracking
-            bi = pcosts.reshape(n_probs, n_chains).argmin(axis=1) + poff
-            for j in np.flatnonzero(pcosts[bi] < gbest_pcost):
-                r = bi[j]
-                gbest_pcost[j] = pcosts[r]
-                gbest_cost[j] = costs[r]
-                g_items[j] = items[r]
-                g_counts[j] = counts[r]
-                g_live[j] = live[r]
+                # --- Metropolis acceptance: per-problem draws, one batched rule
+                temps = t0s / (1.0 + self.rc * it)
+                for j in np.flatnonzero(act_p):
+                    lo = j * n_chains
+                    u_metro[lo : lo + n_chains] = rngs[j].random(n_chains)
+                accept = metropolis_mask(d_tot, temps, u_metro) & active
+                # --- roll back rejected chains (reverse move order)
+                reject = ~accept
+                for m in range(n_moves - 1, -1, -1):
+                    src, dst, applied, s_items, d_items, s_cnt, d_cnt = snaps[m]
+                    idx = np.flatnonzero(reject & applied)
+                    if idx.size:
+                        items[idx, dst[idx]] = d_items[idx]
+                        counts[idx, dst[idx]] = d_cnt[idx]
+                        items[idx, src[idx]] = s_items[idx]
+                        counts[idx, src[idx]] = s_cnt[idx]
+                # --- commit accepted chains
+                costs += np.where(accept, d_e, 0)
+                com = entry_ok & accept[:, None]
+                flat = np.flatnonzero(com.ravel())
+                if flat.size:
+                    rr = flat // width
+                    cc = tslots.ravel()[flat]
+                    bw[rr, cc] = new_w.ravel()[flat]
+                    bh[rr, cc] = new_h.ravel()[flat]
                 if hetero:
-                    g_kinds[j] = bk[r]
-                    g_UK[j] = UK[r]
-                traces[j].append((
-                    time.perf_counter() - t_start,
-                    float(gbest_pcost[j]) if hetero else int(gbest_cost[j]),
-                ))
-            # --- periodic per-problem best-chain exchange + compaction
-            # (gated on the loop-top activity mask: a frozen problem's
-            # standalone run has already exited its loop, so reviving it
-            # here — stale[r] = 0 — would draw RNG the standalone run never
-            # draws and break the fleet parity contract)
-            if self.exchange_every > 0 and (it + 1) % self.exchange_every == 0:
-                worst = pcosts.reshape(n_probs, n_chains).argmax(axis=1) + poff
-                for j in np.flatnonzero((pcosts[worst] > gbest_pcost) & act_p):
-                    r = worst[j]
-                    items[r] = g_items[j]
-                    counts[r] = g_counts[j]
-                    live[r] = g_live[j]
-                    ids = np.where(g_items[j] >= 0, g_items[j], sentinel)
-                    wt = wtab if wtab.ndim == 1 else wtab[j]
-                    dt = dtab if dtab.ndim == 1 else dtab[j]
-                    bw[r] = wt[ids].max(-1)
-                    bh[r] = dt[ids].sum(-1)
-                    costs[r] = gbest_cost[j]
-                    if hetero:
-                        bk[r] = g_kinds[j]
-                        UK[r] = g_UK[j]
-                    best_pcosts[r] = min(best_pcosts[r], gbest_pcost[j])
-                    stale[r] = 0
-                if hetero:
+                    np.copyto(bk, bk_new, where=accept[:, None])
+                    if dUK is not None:
+                        UK += dUK * accept[:, None]
                     pcosts = costs + lam * ovf_rows(UK)
-                order = np.argsort(counts == 0, axis=1, kind="stable")
-                items = np.take_along_axis(items, order[:, :, None], 1)
-                counts = np.take_along_axis(counts, order, 1)
-                bw = np.take_along_axis(bw, order, 1)
-                bh = np.take_along_axis(bh, order, 1)
-                if hetero:
-                    bk = np.take_along_axis(bk, order, 1)
-                live = (counts > 0).sum(1)
-            it += 1
+                else:
+                    pcosts = costs
+                uphill = active & (d_tot > 0)
+                up_prop += uphill.reshape(n_probs, n_chains).sum(axis=1)
+                up_acc += (uphill & accept).reshape(n_probs, n_chains).sum(axis=1)
+                # --- per-chain best / patience bookkeeping
+                steps += active
+                improved = active & (pcosts < best_pcosts)
+                best_pcosts = np.where(improved, pcosts, best_pcosts)
+                stale = np.where(improved, 0, np.where(active, stale + 1, stale))
+                # --- per-problem global-best tracking
+                bi = pcosts.reshape(n_probs, n_chains).argmin(axis=1) + poff
+                for j in np.flatnonzero(pcosts[bi] < gbest_pcost):
+                    r = bi[j]
+                    gbest_pcost[j] = pcosts[r]
+                    gbest_cost[j] = costs[r]
+                    g_items[j] = items[r]
+                    g_counts[j] = counts[r]
+                    g_live[j] = live[r]
+                    if hetero:
+                        g_kinds[j] = bk[r]
+                        g_UK[j] = UK[r]
+                    traces[j].append((
+                        time.perf_counter() - t_start,
+                        float(gbest_pcost[j]) if hetero else int(gbest_cost[j]),
+                    ))
+                # --- periodic per-problem best-chain exchange + compaction
+                # (gated on the loop-top activity mask: a frozen problem's
+                # standalone run has already exited its loop, so reviving it
+                # here — stale[r] = 0 — would draw RNG the standalone run never
+                # draws and break the fleet parity contract)
+                if self.exchange_every > 0 and (it + 1) % self.exchange_every == 0:
+                    worst = pcosts.reshape(n_probs, n_chains).argmax(axis=1) + poff
+                    for j in np.flatnonzero((pcosts[worst] > gbest_pcost) & act_p):
+                        r = worst[j]
+                        items[r] = g_items[j]
+                        counts[r] = g_counts[j]
+                        live[r] = g_live[j]
+                        ids = np.where(g_items[j] >= 0, g_items[j], sentinel)
+                        wt = wtab if wtab.ndim == 1 else wtab[j]
+                        dt = dtab if dtab.ndim == 1 else dtab[j]
+                        bw[r] = wt[ids].max(-1)
+                        bh[r] = dt[ids].sum(-1)
+                        costs[r] = gbest_cost[j]
+                        if hetero:
+                            bk[r] = g_kinds[j]
+                            UK[r] = g_UK[j]
+                        best_pcosts[r] = min(best_pcosts[r], gbest_pcost[j])
+                        stale[r] = 0
+                    if hetero:
+                        pcosts = costs + lam * ovf_rows(UK)
+                    order = np.argsort(counts == 0, axis=1, kind="stable")
+                    items = np.take_along_axis(items, order[:, :, None], 1)
+                    counts = np.take_along_axis(counts, order, 1)
+                    bw = np.take_along_axis(bw, order, 1)
+                    bh = np.take_along_axis(bh, order, 1)
+                    if hetero:
+                        bk = np.take_along_axis(bk, order, 1)
+                    live = (counts > 0).sum(1)
+                it += 1
         # --- write the rebound loop state back (in-place arrays already land
         # in st; these are the names the loop rebinds)
         st.items, st.counts = items, counts
@@ -1134,33 +1141,34 @@ class SimulatedAnnealingPacker:
             st.done = True
 
     def _block_finish(self, st: _BlockState) -> list[_BlockOut]:
-        wall = time.perf_counter() - st.t_start
-        hetero, n_chains = st.hetero, self.n_chains
-        outs: list[_BlockOut] = []
-        for j in range(st.n_probs):
-            lo = j * n_chains
-            chains = [
-                decode_chain_items(
-                    st.probs[j], st.items[r], st.counts[r],
-                    st.bk[r] if hetero else None,
+        with span("repro.sa.finish"):
+            wall = time.perf_counter() - st.t_start
+            hetero, n_chains = st.hetero, self.n_chains
+            outs: list[_BlockOut] = []
+            for j in range(st.n_probs):
+                lo = j * n_chains
+                chains = [
+                    decode_chain_items(
+                        st.probs[j], st.items[r], st.counts[r],
+                        st.bk[r] if hetero else None,
+                    )
+                    for r in range(lo, lo + n_chains)
+                ]
+                gbest = decode_chain_items(
+                    st.probs[j], st.g_items[j], st.g_counts[j],
+                    st.g_kinds[j] if hetero else None,
                 )
-                for r in range(lo, lo + n_chains)
-            ]
-            gbest = decode_chain_items(
-                st.probs[j], st.g_items[j], st.g_counts[j],
-                st.g_kinds[j] if hetero else None,
-            )
-            outs.append(_BlockOut(
-                best=gbest,
-                best_cost=int(st.gbest_cost[j]),
-                trace=st.traces[j],
-                iterations=int(st.steps[lo : lo + n_chains].sum()),
-                chains=chains,
-                incumbent=int(st.pcosts[lo : lo + n_chains].argmin()),
-                uphill=(int(st.up_prop[j]), int(st.up_acc[j])),
-                wall=wall,
-            ))
-        return outs
+                outs.append(_BlockOut(
+                    best=gbest,
+                    best_cost=int(st.gbest_cost[j]),
+                    trace=st.traces[j],
+                    iterations=int(st.steps[lo : lo + n_chains].sum()),
+                    chains=chains,
+                    incumbent=int(st.pcosts[lo : lo + n_chains].argmin()),
+                    uphill=(int(st.up_prop[j]), int(st.up_acc[j])),
+                    wall=wall,
+                ))
+            return outs
 
     def _block_frozen(self, st: _BlockState, j: int) -> bool:
         """True when fleet problem ``j`` has every chain past patience."""

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.problem import BRAM18_MODES
 from repro.kernels.platform import on_tpu
+from repro.spans import span
 
 BACKENDS = ("auto", "python", "ref", "pallas")
 
@@ -118,36 +119,31 @@ def sa_step_deltas(
     import jax.numpy as jnp
 
     if backend == "ref":
-        if hetero:
-            out = _jit_ref_kinds()(
-                jnp.asarray(old_w), jnp.asarray(old_h), jnp.asarray(old_k),
-                jnp.asarray(new_w), jnp.asarray(new_h), jnp.asarray(new_k),
-                kind_tables,
-            )
-        else:
-            out = _jit_ref()(
-                jnp.asarray(old_w), jnp.asarray(old_h),
-                jnp.asarray(new_w), jnp.asarray(new_h), tuple(modes),
-            )
+        fn = _jit_ref_kinds() if hetero else _jit_ref()
     elif backend == "pallas":
-        if hetero:
-            from .kernel import sa_step_deltas_kinds_pallas
+        from .kernel import sa_step_deltas_kinds_pallas, sa_step_deltas_pallas
 
-            out = sa_step_deltas_kinds_pallas(
-                jnp.asarray(old_w), jnp.asarray(old_h), jnp.asarray(old_k),
-                jnp.asarray(new_w), jnp.asarray(new_h), jnp.asarray(new_k),
-                kind_tables,
-            )
-        else:
-            from .kernel import sa_step_deltas_pallas
-
-            out = sa_step_deltas_pallas(
-                jnp.asarray(old_w), jnp.asarray(old_h),
-                jnp.asarray(new_w), jnp.asarray(new_h), tuple(modes),
-            )
+        fn = sa_step_deltas_kinds_pallas if hetero else sa_step_deltas_pallas
     else:
         raise ValueError(f"unknown backend {backend!r}; options: {BACKENDS}")
-    return np.asarray(out, dtype=np.int64)
+    # the puts are made before the call so that each part of the round trip
+    # is a span of its own (repro.spans)
+    with span("repro.dispatch.h2d"):
+        if hetero:
+            args = (
+                jnp.asarray(old_w), jnp.asarray(old_h), jnp.asarray(old_k),
+                jnp.asarray(new_w), jnp.asarray(new_h), jnp.asarray(new_k),
+                kind_tables,
+            )
+        else:
+            args = (
+                jnp.asarray(old_w), jnp.asarray(old_h),
+                jnp.asarray(new_w), jnp.asarray(new_h), tuple(modes),
+            )
+    with span("repro.dispatch.launch"):
+        out = fn(*args)
+    with span("repro.dispatch.d2h"):
+        return np.asarray(out, dtype=np.int64)
 
 
 _SHARD_CACHE: dict = {}
