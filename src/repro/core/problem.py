@@ -44,11 +44,14 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import heapq
 import math
 from functools import reduce
 from typing import Iterable, Sequence
 
 import numpy as np
+
+from repro.spans import span
 
 # Xilinx BRAM18: 16K data bits + 2K parity bits.  Parity bits are usable as
 # data only for aspect widths >= 9, hence the capacity difference per mode.
@@ -764,57 +767,63 @@ def greedy_assign_kinds(sol: Solution) -> Solution:
     Every bin starts on its cheapest kind (which, for capacity-commensurate
     kinds like BRAM18 vs URAM288, is always the finest-grained one); while a
     bounded kind is over its count, the resident bin with the smallest
-    unit-cost regret per freed primitive moves to a kind with room.  Leaves
+    unit-cost regret per freed primitive moves to a kind with room, ties
+    going to the lowest target kind and then the lowest bin.  Leaves
     residual overflow — if no feasible move exists — to the engines'
     inventory penalty.  No-op on single-kind problems; consumes no RNG.
+
+    A pair's regret is fixed until its bin moves, no bin moves twice, and a
+    pair that loses eligibility never regains it, so every (regret, target,
+    bin) key is computed once and the moves are popped from one heap; the
+    only pairs that appear later are those into a kind just relieved of its
+    overflow (docs/DESIGN.md section 17).
     """
     p = sol.problem
     if p.n_kinds == 1 or not p._any_bounded:
         return sol
     sol._refresh()
-    nb = len(sol.bins)
-    nk = p.n_kinds
     g = sol._geom
-    wc = np.empty((nb, nk), dtype=np.int64)
-    prim = np.empty((nb, nk), dtype=np.int64)
-    for bi in range(nb):
-        w, h = int(g[bi, _GW]), int(g[bi, _GH])
-        for k in range(nk):
-            c = p._cost_mode_gap(w, h, k)
-            wc[bi, k] = c[0]
-            prim[bi, k] = c[3]
-    kinds = np.argmin(wc, axis=1).astype(np.int64)
+    prim = p.bin_primitives_many(  # (bins, kinds)
+        g[:, _GW, None], g[:, _GH, None], np.arange(p.n_kinds)
+    )
+    wc = prim * p._kind_weights_arr
+    kinds = np.argmin(wc, axis=1)
     counts = p._kind_counts_arr
-    used = np.zeros(nk, dtype=np.int64)
-    ar = np.arange(nb)
-    np.add.at(used, kinds, prim[ar, kinds])
-    # move selection is vectorized over bins per candidate target kind:
-    # large heterogeneous inits (hundreds of bins x population size) would
-    # otherwise spend seconds in nested python loops
-    for _ in range(nb + 1):
-        over = (counts >= 0) & (used > counts)
-        if not over.any():
-            break
-        cur_wc = wc[ar, kinds]
-        cur_prim = prim[ar, kinds]
-        movable = over[kinds] & (cur_prim > 0)
-        best = None  # (regret per freed primitive, bin, target kind)
-        for j in range(nk):
-            cand = movable & (kinds != j)
-            if counts[j] >= 0:
-                cand &= used[j] + prim[:, j] <= counts[j]
-            if not cand.any():
-                continue
-            regret = np.where(cand, (wc[:, j] - cur_wc) / cur_prim, np.inf)
-            bi = int(np.argmin(regret))
-            if best is None or regret[bi] < best[0]:
-                best = (float(regret[bi]), bi, j)
-        if best is None:
-            break
-        _, bi, j = best
-        used[kinds[bi]] -= prim[bi, kinds[bi]]
-        kinds[bi] = j
-        used[j] += prim[bi, j]
+    ar = np.arange(len(kinds))
+    cur_wc = wc[ar, kinds]
+    cur_prim = prim[ar, kinds]
+    used = np.zeros(p.n_kinds, dtype=np.int64)
+    np.add.at(used, kinds, cur_prim)
+    over = (counts >= 0) & (used > counts)
+
+    def pairs_into(j: int) -> list[tuple[float, int, int]]:
+        # only unmoved bins sit on an over kind, so their cur_* rows hold
+        cand = over[kinds] & (cur_prim > 0)
+        if counts[j] >= 0:
+            cand &= used[j] + prim[:, j] <= counts[j]
+        b = np.flatnonzero(cand)
+        regret = (wc[b, j] - cur_wc[b]) / cur_prim[b]
+        return list(zip(regret.tolist(), [j] * len(b), b.tolist()))
+
+    n_over = int(over.sum())
+    if n_over:
+        with span("repro.nfd.kinds.walk"):
+            heap = [x for j in np.flatnonzero(~over).tolist() for x in pairs_into(j)]
+            heapq.heapify(heap)
+            while n_over and heap:
+                _, j, b = heapq.heappop(heap)
+                s = kinds[b]
+                if not over[s] or (counts[j] >= 0 and used[j] + prim[b, j] > counts[j]):
+                    continue  # ineligible now, and so for good
+                used[s] -= prim[b, s]
+                kinds[b] = j
+                used[j] += prim[b, j]
+                if used[s] <= counts[s]:
+                    over[s] = False
+                    n_over -= 1
+                    if n_over:
+                        for x in pairs_into(int(s)):
+                            heapq.heappush(heap, x)
     changed = np.flatnonzero(kinds != sol.kinds)
     if changed.size:
         sol.kinds[:] = kinds

@@ -13,7 +13,9 @@ The spans, from the outside in (docs/DESIGN.md section 17):
 
 * ``repro.sa.start`` / ``repro.sa.finish`` - a fleet's encode and decode;
 * ``repro.sa.seed`` - the NFD chain seeding inside ``start``, and inside it
-  ``repro.nfd.kinds``, each seed's greedy RAM-kind assignment;
+  ``repro.nfd.kinds``, each seed's greedy RAM-kind assignment, and inside
+  that ``repro.nfd.kinds.walk``, its sorted walk of moves, open only when
+  some bounded kind starts over its count;
 * ``repro.sa.propose`` / ``repro.sa.accept`` - one annealing step before
   and after its delta-cost request;
 * ``repro.dispatch.h2d`` / ``.launch`` / ``.d2h`` - one kernel call of

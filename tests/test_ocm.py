@@ -9,6 +9,8 @@ Golden costs are hand-checked:
 * On a BRAM18+URAM288 inventory the shared cost unit is 18432 bits, so one
   URAM weighs 16 units and all costs stay exactly comparable.
 """
+import random
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,8 @@ import repro.core as c
 from repro.core.ga import GeneticPacker, buffer_swap, kind_reassign
 from repro.core.nfd import nfd_from_scratch, nfd_repack
 from repro.core.problem import (
+    _GH,
+    _GW,
     BRAM18,
     BRAM36,
     LUTRAM64,
@@ -23,6 +27,7 @@ from repro.core.problem import (
     Buffer,
     OCMInventory,
     PackingProblem,
+    RAMKind,
     Solution,
     decode_chain_items,
     encode_chain_items,
@@ -199,6 +204,166 @@ def test_greedy_assign_kinds_relieves_overflow():
     sol.validate()
     assert sol.inventory_overflow() == 0
     assert sol.cost() == sol.cost_full()
+
+
+def _rescan_assign_kinds(sol: Solution) -> Solution:
+    """Test oracle: the original greedy kind assignment, which rescans
+    every bin for every move."""
+    p = sol.problem
+    if p.n_kinds == 1 or not p._any_bounded:
+        return sol
+    sol._refresh()
+    nb = len(sol.bins)
+    nk = p.n_kinds
+    g = sol._geom
+    wc = np.empty((nb, nk), dtype=np.int64)
+    prim = np.empty((nb, nk), dtype=np.int64)
+    for bi in range(nb):
+        w, h = int(g[bi, _GW]), int(g[bi, _GH])
+        for k in range(nk):
+            c = p._cost_mode_gap(w, h, k)
+            wc[bi, k] = c[0]
+            prim[bi, k] = c[3]
+    kinds = np.argmin(wc, axis=1).astype(np.int64)
+    counts = p._kind_counts_arr
+    used = np.zeros(nk, dtype=np.int64)
+    ar = np.arange(nb)
+    np.add.at(used, kinds, prim[ar, kinds])
+    for _ in range(nb + 1):
+        over = (counts >= 0) & (used > counts)
+        if not over.any():
+            break
+        cur_wc = wc[ar, kinds]
+        cur_prim = prim[ar, kinds]
+        movable = over[kinds] & (cur_prim > 0)
+        best = None  # (regret per freed primitive, bin, target kind)
+        for j in range(nk):
+            cand = movable & (kinds != j)
+            if counts[j] >= 0:
+                cand &= used[j] + prim[:, j] <= counts[j]
+            if not cand.any():
+                continue
+            regret = np.where(cand, (wc[:, j] - cur_wc) / cur_prim, np.inf)
+            bi = int(np.argmin(regret))
+            if best is None or regret[bi] < best[0]:
+                best = (float(regret[bi]), bi, j)
+        if best is None:
+            break
+        _, bi, j = best
+        used[kinds[bi]] -= prim[bi, kinds[bi]]
+        kinds[bi] = j
+        used[j] += prim[bi, j]
+    changed = np.flatnonzero(kinds != sol.kinds)
+    if changed.size:
+        sol.kinds[:] = kinds
+        sol.touch(*[int(b) for b in changed])
+    return sol
+
+
+def _nfd_seeds(prob, n, seed=0):
+    """NFD packings with their kind lanes reset to kind 0."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        s = nfd_from_scratch(prob, rng)
+        s.kinds[:] = 0
+        s.invalidate()
+        out.append(s)
+    return out
+
+
+def _singletons(specs, kinds, counts):
+    """One bin per (width, depth, how many) group, in order."""
+    bufs = [Buffer(w, d, 0) for w, d, m in specs for _ in range(m)]
+    prob = PackingProblem(bufs, ocm=OCMInventory(kinds, counts), max_items=1)
+    return prob.singleton_solution()
+
+
+def _random_three_kind(n_problems):
+    """LUTRAM64/BRAM18/URAM288 problems, the kinds in a random order (ties
+    in unit cost go to the first, so the order decides which start over)."""
+    rng = np.random.default_rng(7)
+    out = []
+    for t in range(n_problems):
+        bufs = [
+            Buffer(int(rng.choice([1, 2, 4, 8, 16, 18, 36, 72, 144])),
+                   int(rng.choice([8, 16, 64, 500, 1024, 2048, 4096, 9000])),
+                   int(rng.integers(0, 4)))
+            for _ in range(int(rng.integers(5, 60)))
+        ]
+        kinds = tuple(rng.permutation([LUTRAM64, BRAM18, URAM288]))
+        counts = tuple(int(x) for x in rng.integers(-1, 40, size=3))
+        prob = PackingProblem(
+            bufs, ocm=OCMInventory(kinds, counts), max_items=int(rng.integers(1, 5)),
+        )
+        out += _nfd_seeds(prob, 1, seed=t)
+    return out
+
+
+def _two_over_relief():
+    """BRAM18 and LUTRAM64 both start over.  A (18, 2048) bin is 2 BRAM18
+    and moves to URAM288 first (regret 2016 per primitive, against 4607 for
+    a (4, 16) LUTRAM bin); that leaves one BRAM18 free, which a LUTRAM bin
+    (regret 287) then takes."""
+    return [_singletons([(4, 16, 6), (18, 2048, 4)],
+                        (BRAM18, LUTRAM64, URAM288), (7, 2, 6))]
+
+
+def _regret_ties():
+    """Equal regrets across bins and across target kinds: a (36, 1024) bin
+    costs 2 units on BRAM18, on BRAM36 and on a BRAM18 twin alike."""
+    twin = RAMKind("BRAM18_TWIN", BRAM18.modes, BRAM18.capacity_bits)
+    specs = [(36, 1024, 5), (18, 1024, 4), (36, 2048, 3), (1, 100, 2)]
+    return [_singletons(specs, (BRAM18, BRAM36, twin), (6, 3, 5)),
+            _singletons(specs, (BRAM18, twin, BRAM36), (4, 4, -1))]
+
+
+KIND_CASES = {
+    "rn152-u50": lambda: _nfd_seeds(c.get_problem("RN152-W1A2", device="U50"), 3),
+    "table1-zu7ev": lambda: [
+        s for name in ("CNV-W2A2", "Tincy-YOLO", "RN50-W1A2", "RN101-W1A2")
+        for s in _nfd_seeds(c.get_problem(name, device="ZU7EV"), 2)
+    ],
+    "random-3-kind": lambda: _random_three_kind(120),
+    "two-over-relief": _two_over_relief,
+    "regret-ties": _regret_ties,
+    "unbounded-target": lambda: [
+        _singletons([(32, 4096, 20)], (BRAM18, URAM288), (40, -1)),
+        hetero_problem(np.random.default_rng(3)).singleton_solution(),
+    ],
+    "infeasible": lambda: [
+        _singletons([(32, 4096, 20), (72, 4096, 3)], (BRAM18, URAM288), (10, 2)),
+    ],
+    "single-kind": lambda: [
+        c.get_problem("CNV-W1A1").singleton_solution(),
+        _singletons([(32, 4096, 5)], (BRAM18, URAM288), (-1, -1)),
+    ],
+}
+
+
+@pytest.mark.parametrize("case", list(KIND_CASES))
+def test_greedy_assign_kinds_matches_rescan(case):
+    """The sorted walk gives the original rescan's kind lane, cost and
+    residual overflow, touches only the changed bins and draws no RNG."""
+    for sol in KIND_CASES[case]():
+        want = _rescan_assign_kinds(sol.copy())
+        before = sol.kinds.copy()
+        np_state, py_state = np.random.get_state(), random.getstate()
+        got = greedy_assign_kinds(sol)
+        assert got is sol
+        assert random.getstate() == py_state
+        after = np.random.get_state()
+        assert np.array_equal(after[1], np_state[1])
+        assert after[:1] + after[2:] == np_state[:1] + np_state[2:]
+        np.testing.assert_array_equal(sol.kinds, want.kinds)
+        assert sol.cost() == want.cost() == sol.cost_full()
+        assert sol.inventory_overflow() == want.inventory_overflow()
+        if case == "single-kind":
+            np.testing.assert_array_equal(sol.kinds, before)
+        if case == "infeasible":
+            assert sol.inventory_overflow() > 0
+        if case == "two-over-relief":  # a LUTRAM bin lands on the relieved BRAM18
+            assert list(sol.kinds[:6]).count(0) == 1
 
 
 def test_chain_codecs_round_trip_kinds():

@@ -74,6 +74,32 @@ def test_sweep_bit_identical_with_spans_on():
     assert on == off
 
 
+@pytest.mark.parametrize("problem, walks", [
+    ("overflowing", True),  # BRAM18 over on every seed
+    ("roomy", False),  # two kinds, neither over
+    ("single-kind", False),
+])
+def test_kind_walk_span_once_per_overflowing_seed(problem, walks, monkeypatch):
+    prob = {
+        "overflowing": _hetero_problem(),
+        "roomy": PackingProblem([Buffer(36, 4096, i % 4) for i in range(40)],
+                                ocm=OCMInventory((BRAM18, URAM288), (1000, 64)),
+                                max_items=4),
+        "single-kind": c.get_problem("CNV-W1A1"),
+    }[problem]
+    names = []
+
+    @contextlib.contextmanager
+    def record(name):
+        names.append(name)
+        yield
+
+    monkeypatch.setattr(spans, "_annotation", record)
+    c.pack(prob, "sa-s", seed=3, n_chains=4, max_iterations=5, backend="python")
+    assert names.count("repro.nfd.kinds") == 4  # one per fresh chain
+    assert names.count("repro.nfd.kinds.walk") == (4 if walks else 0)
+
+
 def test_python_backend_with_spans_off_imports_no_jax():
     code = (
         "import sys\n"
