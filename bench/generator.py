@@ -1,9 +1,10 @@
 """The one traffic generator: every mix is a data file under bench/traffic/.
 
 A mix names its ``entry`` (``pack``, ``sweep`` or ``serve``) and the
-parameters this module reads.  Everything random comes from the run's
-``--seed`` through ``numpy``'s ``SeedSequence``, so the same seed gives the
-same solver seeds, arrivals and problem picks.
+parameters this module reads.  Solver seeds, and the sample of answers
+the check replays, come from the run's ``--seed`` through ``numpy``'s
+``SeedSequence``, so the same seed gives the same inputs; an open loop's
+arrival schedule comes from a fixed stream and is the same for every seed.
 
 The arrival arithmetic (exponential gaps at a fixed rate, Zipf popularity
 over problem ranks) is copied from the program's ``serve/traffic.py``
@@ -45,12 +46,15 @@ class Arrival:
 
 def arrivals(seed: int, seconds: float, n_problems: int, rate_hz: float,
              zipf_a: float, revisit: float) -> list[Arrival]:
-    """Poisson arrivals in ``[0, seconds)``; Zipf popularity by list order.
+    """Poisson arrivals in ``[0, seconds)``; Zipf popularity by list order,
+    uniform picks at ``zipf_a`` 0.
 
-    Every seed gets the same set of gaps, problem picks and repeat flags,
-    drawn once from a fixed stream, in an order of its own; the seed also
-    draws the solver seeds and which earlier task a repeat names.  So runs
-    with different seeds offer the same work, as runs of one seed do."""
+    The schedule (each request's due time, its problem, and for a repeat
+    which earlier task it repeats) comes from a fixed stream and is the
+    same for every seed; the seed draws the solver seeds of the fresh
+    tasks.  An open loop's latencies hang on the order of its requests and
+    not only on their set, so this is what keeps runs with different seeds
+    offering the same work, as runs of one seed do."""
     fixed = _rng(0, "arrival-set")
     ranks = np.arange(1, n_problems + 1, dtype=np.float64)
     popularity = ranks**-zipf_a
@@ -65,20 +69,16 @@ def arrivals(seed: int, seconds: float, n_problems: int, rate_hz: float,
     n = len(gaps)
     picks = fixed.choice(n_problems, size=n, p=popularity)
     repeats = fixed.random(n) < revisit
+    if n:
+        repeats[0] = False  # the first request has nothing to repeat
 
     rng = _rng(seed, "arrivals")
-    gaps = rng.permutation(np.asarray(gaps))
-    picks = rng.permutation(picks)
-    repeats = rng.permutation(repeats)
-    if n and repeats[0]:  # the first request has nothing to repeat
-        j = int(np.argmin(repeats))
-        repeats[0], repeats[j] = False, True
     out: list[Arrival] = []
     due = 0.0
     for i in range(n):
         due += float(gaps[i])
         if repeats[i]:
-            prev = out[int(rng.integers(i))]
+            prev = out[int(fixed.integers(i))]
             out.append(Arrival(due, prev.problem, prev.seed, True))
         else:
             out.append(Arrival(due, int(picks[i]), int(rng.integers(0, SEED_SPACE)), False))

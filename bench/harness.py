@@ -25,6 +25,7 @@ import dataclasses
 import gc
 import importlib.util
 import json
+import math
 import shutil
 import sys
 import tempfile
@@ -39,6 +40,7 @@ from bench.reference import ReferenceProblem, audit, canonical, replay_sa_s
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
+GRACE_S = 60.0  # how long past the window an open loop waits for an answer
 
 
 class CellError(Exception):
@@ -224,6 +226,8 @@ class Window:
     metrics: dict
     notes: dict
     stats: dict | None = None
+    requests: list | None = None  # (due, problem, seed), perf_counter seconds
+    batches: list | None = None  # (start, end, {(problem, seed)}) per solve_batch
 
 
 def _warm_kernel_rows(env: Env, row_counts) -> None:
@@ -325,24 +329,36 @@ def warm_serve(env: Env) -> None:
 
 
 @contextlib.contextmanager
-def _batch_spans(on: bool):
-    """Span around every micro-batch the service hands to the solver."""
-    if not on:
-        yield
-        return
+def _batch_spans(env: Env, batches: list):
+    """Records every micro-batch the service hands to the solver as
+    ``(start, end, {(problem, seed)})`` on ``perf_counter``, under a
+    ``bench.batch`` span in a traced run."""
     from repro.core import dse
 
     orig = dse.solve_batch
+    index = {id(p): i for i, p in enumerate(env.problems)}
 
-    def solve_batch(*a, **kw):
-        with _span("bench.batch", True):
-            return orig(*a, **kw)
+    def solve_batch(problems, *a, seeds, **kw):
+        keys = {(index[id(p)], int(s)) for p, s in zip(problems, seeds)}
+        start = time.perf_counter()
+        try:
+            with _span("bench.batch", env.traced):
+                return orig(problems, *a, seeds=seeds, **kw)
+        finally:
+            batches.append((start, time.perf_counter(), keys))
 
     dse.solve_batch = solve_batch
     try:
         yield
     finally:
         dse.solve_batch = orig
+
+
+TAIL_QS = (0.5, 0.8, 0.9, 0.95)  # request latency quantiles an open loop reports
+
+
+def _ms(values, q):
+    return generator.nearest_rank(values, q) * 1e3 if values else None
 
 
 def run_serve(env: Env, seconds: float) -> Window:
@@ -353,18 +369,22 @@ def run_serve(env: Env, seconds: float) -> Window:
                               float(t["rate_hz"]), float(t["zipf_a"]),
                               float(t["revisit"]))
     store_dir = tempfile.mkdtemp(prefix="bench-store-")
-    latency, lag, answers, by_due = [], [], [], []
+    # a request that fails or is never answered keeps +inf: it misses any
+    # latency limit and still counts in the rank
+    latency = [math.inf] * len(plan)
+    lag, answers, batches = [], [], []
     failed = 0
+    t0 = None
 
     async def drive():
-        nonlocal failed
+        nonlocal failed, t0
         async with PackingService(
             env.algorithm, store_dir=store_dir, backend=env.backend,
             max_seconds=env.max_seconds, **t["service"], **env.solver,
         ) as svc:
             t0 = time.perf_counter()
 
-            async def one(a):
+            async def one(i, a):
                 nonlocal failed
                 due = t0 + a.due_s
                 delay = due - time.perf_counter()
@@ -373,43 +393,47 @@ def run_serve(env: Env, seconds: float) -> Window:
                 lag.append(time.perf_counter() - due)
                 try:
                     res = await svc.pack(env.problems[a.problem], seed=a.seed)
-                    answers.append(answer_of(res, a.problem, a.seed))
                 except Exception:
                     failed += 1
                     traceback.print_exc(file=sys.stderr)
-                latency.append(time.perf_counter() - due)
-                by_due.append((a.due_s, latency[-1]))
+                    return
+                answers.append(answer_of(res, a.problem, a.seed))
+                latency[i] = time.perf_counter() - due
 
-            # an answer may come late, up to a minute past the window; one
+            # an answer may come late, up to GRACE_S past the window; one
             # that never comes counts as failed
-            tasks = [asyncio.ensure_future(one(a)) for a in plan]
-            _, late = await asyncio.wait(tasks, timeout=seconds + 60.0)
+            tasks = [asyncio.ensure_future(one(i, a)) for i, a in enumerate(plan)]
+            _, late = await asyncio.wait(tasks, timeout=seconds + GRACE_S)
             for task in late:
                 task.cancel()
                 failed += 1
             return time.perf_counter() - t0, svc.stats()
 
     try:
-        with _batch_spans(env.traced):
+        with _batch_spans(env, batches):
             elapsed, stats = asyncio.run(drive())
     finally:
         shutil.rmtree(store_dir, ignore_errors=True)
-    p95 = generator.nearest_rank(latency, 0.95) * 1e3 if latency else None
+    requests = [(t0 + a.due_s, a.problem, a.seed) for a in plan]
+    tails = {f"request_p{round(100 * q)}_ms": _ms(latency, q) for q in TAIL_QS}
     # a backlog that grows through the window shows as later requests
     # waiting longer than earlier ones
-    early = [lat for due, lat in by_due if due < seconds / 2]
-    late = [lat for due, lat in by_due if due >= seconds / 2]
+    halves = ([lat for a, lat in zip(plan, latency) if a.due_s < seconds / 2],
+              [lat for a, lat in zip(plan, latency) if a.due_s >= seconds / 2])
     notes = {
-        "p50_ms_first_half": generator.nearest_rank(early, 0.5) * 1e3 if early else None,
-        "p50_ms_second_half": generator.nearest_rank(late, 0.5) * 1e3 if late else None,
-        "requests": len(plan), "repeats": sum(a.repeat for a in plan),
+        "requests": len(plan), **tails,
+        "p50_ms_first_half": _ms(halves[0], 0.5),
+        "p50_ms_second_half": _ms(halves[1], 0.5),
+        "repeats": sum(a.repeat for a in plan),
         "solved": stats["solved"], "batches": stats["batches"],
         "coalesced": stats["coalesced"], "cache_hits": stats["cache_hits_mem"],
-        "p50_ms": generator.nearest_rank(latency, 0.5) * 1e3 if latency else None,
+        "batch_solve_ms_p50": _ms([e - s for s, e, _ in batches], 0.5),
         "generator_lag_max_ms": max(lag) * 1e3 if lag else None,
-        "generator_lag_p95_ms": generator.nearest_rank(lag, 0.95) * 1e3 if lag else None,
+        "generator_lag_p95_ms": _ms(lag, 0.95),
     }
-    return Window(answers, len(plan), failed, elapsed, {"request_p95_ms": p95}, notes, stats)
+    metrics = {k: v if v is not None and math.isfinite(v) else None for k, v in tails.items()}
+    return Window(answers, len(plan), failed, elapsed, metrics, notes, stats,
+                  requests, batches)
 
 
 LOOPS = {
@@ -483,6 +507,9 @@ class RunView:
     calls: list
     peaks: dict
     stats: dict | None
+    requests: list | None = None
+    batches: list | None = None
+    elapsed_s: float | None = None  # the window's length on the host's clock
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
@@ -553,7 +580,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     device = {"platform": platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": memory}
     out_metrics: dict = {}
-    breakdown = None
+    breakdown = summary = None
     if trace:
         from bench.tracing import reduce_trace
 
@@ -561,11 +588,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         shutil.rmtree(tmp, ignore_errors=True)
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
-        view = RunView(summary, spans.calls, peaks, win.stats)
+    view = RunView(summary, spans.calls if spans is not None else [], peaks, win.stats,
+                   win.requests, win.batches, win.elapsed_s)
+    # an untraced run prints, as notes, the per-layer metrics it can read
+    # without a trace
+    layer = {m["name"]: metric_reader(cell.root, m["name"])(view) for m in cell.per_layer}
+    if trace:
         for m in cell.per_layer:
-            value = metric_reader(cell.root, m["name"])(view)
-            if value is not None:
-                out_metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+            if layer[m["name"]] is not None:
+                out_metrics[m["name"]] = {"value": layer[m["name"]], "unit": m["unit"]}
         ops = sorted(summary.op_seconds.items(), key=lambda x: -x[1])[:10]
         breakdown = {"device_ops": [[n, s] for n, s in ops],
                      "idle_gaps": [[n, s] for n, s in summary.gaps[:10]]}
@@ -575,8 +606,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         values = dict(win.metrics, setup_s=setup_s)
         for m in cell.end_to_end:
             if values.get(m["name"]) is None:
+                if not correct:  # a run gone wrong still prints its line
+                    continue
                 raise CellError(f"the {entry!r} loop gives no {m['name']!r}")
             out_metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+        win.notes.update((k, v) for k, v in layer.items() if v is not None)
 
     for k, v in {"window_s": win.elapsed_s, **win.notes, **check_notes}.items():
         print(f"note: {k}={v}", file=sys.stderr)

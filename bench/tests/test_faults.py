@@ -62,8 +62,10 @@ def _plant(monkeypatch, fault):
 @pytest.mark.parametrize("cell", CELLS)
 def test_broken_step_is_not_correct(cell, fault, monkeypatch):
     _plant(monkeypatch, fault)
+    # the devices' counts cut so that they bind at this size, as they do at
+    # the cells' own sizes: a fault then moves the answers it touches
     line = run_tiny(ROOT, cell, seed=2**31 + 29, seconds=0.6, rows=4, per_row=8,
-                    steps=30, replay_all=True)
+                    steps=30, counts=[6, 1], replay_all=True)
     assert line["correct"] is False
     found = line["checks"]
     assert found["audit_failures"]["value"] + found["replay_mismatches"]["value"] > 0

@@ -221,6 +221,31 @@ def dispatch_us_per_call(t: TraceSummary) -> float | None:
     return 1e6 * sum(d) / len(d) if d else None
 
 
+def lane_busy_pct(batches, elapsed_s: float | None) -> float | None:
+    """Share of the window in which the service's lane solves a batch: the
+    union of the recorded ``(start, end, keys)`` batches over its length."""
+    if not batches or not elapsed_s:
+        return None
+    return 100.0 * sum(e - s for s, e in _union((s, e) for s, e, _ in batches)) / elapsed_s
+
+
+def queue_waits(requests, batches) -> list[float]:
+    """Seconds from each request's due time to the start of the batch that
+    solved it.  A request is paired with the first batch that starts at or
+    after its due time and holds its (problem, seed); one whose task was in
+    a batch already started, or answered, is a hit and has no wait."""
+    starts: dict = {}
+    for start, _, keys in batches:
+        for key in keys:
+            starts.setdefault(key, []).append(start)
+    waits = []
+    for due, problem, seed in requests:
+        later = [s for s in starts.get((problem, seed), ()) if s >= due]
+        if later:
+            waits.append(min(later) - due)
+    return waits
+
+
 def device_idle_pct(t: TraceSummary) -> float | None:
     if t is None or t.n_chips == 0 or t.window_s <= 0:
         return None
