@@ -1,10 +1,10 @@
 """The control: the plain reference put in the program's place, with one
-guarantee of the configuration broken.  It anneals without the inventory
-penalty: its acceptance ignores the device's RAM counts, the host-side work
-a faster step would be tempted to drop.  The counts bind for RN152 on the
-U50 and for five of the eight accelerators on the ZU7EV, and every check
-replays the largest problem, so its packings and traces part from the
-penalized answers.  The control's answers go through the same check as the
+piece of the solver's work left out, the host-side work a faster step would
+be tempted to drop.  Each solver's file says which (``control`` in
+``bench/solvers/<algorithm>.py``): SA-S anneals without the inventory
+penalty, GA-NFD selects without the layer term of its fitness.  Every check
+replays the largest problem, so the control's packings and traces part from
+the program's.  The control's answers go through the same check as the
 program's, which has to find them not correct.
 
     python3 -m bench.control --workload <cell> --seed <n> --seconds <s>
@@ -24,19 +24,14 @@ import types
 from pathlib import Path
 
 from bench import harness
-from bench.reference import replay_sa_s
 
 
-def _solve(cfg: dict, ref, prob, seed: int, on_chip: bool):
+def _solve(cfg: dict, rules, ref, prob, seed: int, on_chip: bool):
     """The control's answer, in the program's result type (the service
     stores and serves that type)."""
     from repro.core.problem import PackingResult, Solution
 
-    s = cfg["solver"]
-    hyper = {k: s[k] for k in ("sa_t0", "sa_rc", "p_adm_w", "p_adm_h", "swap_moves",
-                               "exchange_every", "ladder_min", "ladder_max", "p_kind")}
-    out = replay_sa_s(ref, int(seed), int(cfg["n_chains"]), int(cfg["max_iterations"]),
-                      penalty=0.0, **hyper)
+    out = rules.control(ref, int(seed), cfg)
     sol = Solution(prob, out["bins"], kinds=out["kinds"])
     return PackingResult(
         solution=sol, cost=out["cost"], efficiency=sol.efficiency(), wall_time_s=0.0,
@@ -49,17 +44,18 @@ def _solve(cfg: dict, ref, prob, seed: int, on_chip: bool):
 @contextlib.contextmanager
 def in_place_of_program(cfg: dict, on_chip: bool):
     """Answer the program's entry points (``pack``, ``pack_sweep``,
-    ``solve_batch``) with the penalty-free reference."""
+    ``solve_batch``) with the solver's control."""
     import repro.core as core
     from repro.core import dse
 
+    rules = harness.solver_file(cfg["solver"]["algorithm"])
     by_name = {r.name: r for r in harness.reference_problems(cfg)}
 
     def pack(prob, algorithm, seed=0, **_):
-        return _solve(cfg, by_name[prob.name], prob, seed, on_chip)
+        return _solve(cfg, rules, by_name[prob.name], prob, seed, on_chip)
 
-    def solve_batch(problems, algorithm="sa-s", seeds=None, **_):
-        return [_solve(cfg, by_name[p.name], p, s, on_chip)
+    def solve_batch(problems, algorithm=None, seeds=None, **_):
+        return [_solve(cfg, rules, by_name[p.name], p, s, on_chip)
                 for p, s in zip(problems, seeds)]
 
     def pack_sweep(problems, algorithm, seeds=None, **_):

@@ -2,8 +2,10 @@
 
 A cell is an entry of ``BENCHMARK.json`` ``workloads``.  Everything that
 belongs to it is found by name: the configuration's file (``configs``
-``file``), the traffic mix ``bench/traffic/<traffic>.json`` and each
-per-layer metric's reader ``bench/metrics/<metric>.py``.  The mix's
+``file``), its solver's file ``bench/solvers/<algorithm>.py`` (settings,
+budget, warm-up, reference replay and control), the traffic mix
+``bench/traffic/<traffic>.json`` and each per-layer metric's reader
+``bench/metrics/<metric>.py``.  The mix's
 ``entry`` picks one of three loops, which call the program's own entry
 points:
 
@@ -35,12 +37,13 @@ import traceback
 from pathlib import Path
 
 from bench import generator
-from bench.reference import ReferenceProblem, audit, canonical, replay_sa_s
+from bench.reference import INVENTORY_PENALTY, ReferenceProblem, audit, canonical
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
 GRACE_S = 60.0  # how long past the window an open loop waits for an answer
+SOLVERS = Path(__file__).resolve().parent / "solvers"
 
 
 class CellError(Exception):
@@ -122,16 +125,25 @@ def reference_problems(cfg: dict) -> list[ReferenceProblem]:
     return out
 
 
+def solver_file(algorithm: str):
+    """The module ``bench/solvers/<algorithm>.py``: ``settings(cfg)``,
+    ``budget(cfg)``, ``warm(cfg)``, ``replay(ref, seed, cfg)`` and
+    ``control(ref, seed, cfg)`` of one of the program's solvers."""
+    path = SOLVERS / f"{algorithm}.py"
+    if not path.is_file():
+        raise CellError(f"no solver file for algorithm {algorithm!r} ({path.name})")
+    spec = importlib.util.spec_from_file_location(f"bench_solver_{algorithm}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def solver_settings(cfg: dict) -> tuple[str, float, dict]:
     """(algorithm, wall cap, keyword arguments) of the program's solver."""
-    s = dict(cfg["solver"])
-    algorithm = s.pop("algorithm")
-    patience, max_seconds = s.pop("patience"), s.pop("max_seconds")
-    if patience is not None or max_seconds is not None:
+    s = cfg["solver"]
+    if s["patience"] is not None or s["max_seconds"] is not None:
         raise CellError("the benchmark runs on iteration budgets alone")
-    kw = dict(s, n_chains=int(cfg["n_chains"]),
-              max_iterations=int(cfg["max_iterations"]), patience=10**12)
-    return algorithm, 1e12, kw
+    return s["algorithm"], 1e12, solver_file(s["algorithm"]).settings(cfg)
 
 
 @dataclasses.dataclass
@@ -215,6 +227,7 @@ class Env:
     backend: str
     seed: int
     traced: bool = False
+    rules: object = None  # the solver's file (``solver_file``)
 
 
 @dataclasses.dataclass
@@ -230,30 +243,14 @@ class Window:
     batches: list | None = None  # (start, end, {(problem, seed)}) per solve_batch
 
 
-def _warm_kernel_rows(env: Env, row_counts) -> None:
-    """Compile (or load) the step kernel at every fleet row count."""
-    import numpy as np
-
-    from repro.kernels.binpack_sa_step.ops import sa_step_deltas
-
-    prob = env.problems[0]
-    width = 2 * max(int(env.solver["swap_moves"]), 1)
-    for rows in row_counts:
-        z = np.zeros((rows, width), dtype=np.int32)
-        if prob.n_kinds > 1:
-            sa_step_deltas(z, z, z, z, backend=env.backend, old_k=z, new_k=z,
-                           kind_tables=prob.kind_tables)
-        else:
-            sa_step_deltas(z, z, z, z, modes=prob.kind_tables[0][1], backend=env.backend)
-
-
 def warm_pack(env: Env) -> None:
     import repro.core as c
 
-    _warm_kernel_rows(env, [env.solver["n_chains"]])
+    override, compile_kernel = env.rules.warm(env.cell.config)
+    compile_kernel(env.problems, [1], env.backend)
     for prob in env.problems:  # fills the problem's own lookup caches
         c.pack(prob, env.algorithm, seed=0, max_seconds=env.max_seconds,
-               backend=env.backend, **{**env.solver, "max_iterations": 1})
+               backend=env.backend, **{**env.solver, **override})
 
 
 def run_pack(env: Env, seconds: float) -> Window:
@@ -284,9 +281,10 @@ def run_pack(env: Env, seconds: float) -> Window:
 def warm_sweep(env: Env) -> None:
     import repro.core as c
 
+    override, _ = env.rules.warm(env.cell.config)
     c.pack_sweep(env.problems, env.algorithm, seeds=list(range(len(env.problems))),
                  max_seconds=env.max_seconds, backend=env.backend,
-                 **{**env.solver, "max_iterations": 1})
+                 **{**env.solver, **override})
 
 
 def run_sweep(env: Env, seconds: float) -> Window:
@@ -318,14 +316,14 @@ def warm_serve(env: Env) -> None:
     from repro.core.dse import solve_batch
 
     t = env.cell.traffic["service"]
-    chains = env.solver["n_chains"]
-    _warm_kernel_rows(env, [chains * k for k in range(1, int(t["max_batch"]) + 1)])
+    override, compile_kernel = env.rules.warm(env.cell.config)
+    compile_kernel(env.problems, range(1, int(t["max_batch"]) + 1), env.backend)
     probs = env.problems
     for lo in range(0, len(probs), int(t["max_batch"])):
         part = probs[lo: lo + int(t["max_batch"])]
         solve_batch(part, env.algorithm, seeds=[0] * len(part),
                     max_seconds=env.max_seconds, backend=env.backend,
-                    **{**env.solver, "max_iterations": 1})
+                    **{**env.solver, **override})
 
 
 @contextlib.contextmanager
@@ -449,11 +447,12 @@ def check(env: Env, win: Window, on_chip: bool) -> tuple[bool, dict, dict]:
     seed, always with the largest problem, is replayed by the reference."""
     cfg = env.cell.config
     refs = reference_problems(cfg)
-    chains, iters = int(cfg["n_chains"]), int(cfg["max_iterations"])
+    budget = env.rules.budget(cfg)
+    penalty = cfg["solver"].get("inventory_penalty", INVENTORY_PENALTY)
     audit_failures = 0
     for a in win.answers:
         found = audit(refs[a.problem], a.bins, a.kinds, a.cost, a.trace[-1],
-                      a.iterations, chains * iters, cfg["solver"]["inventory_penalty"])
+                      a.iterations, budget, penalty)
         if found:
             audit_failures += 1
             print(f"audit: {refs[a.problem].name} seed {a.seed}: {found}", file=sys.stderr)
@@ -467,16 +466,12 @@ def check(env: Env, win: Window, on_chip: bool) -> tuple[bool, dict, dict]:
     big = max(range(len(keys)), key=lambda j: refs[keys[j][0]].n) if keys else None
     picked = generator.sample(env.seed, len(keys),
                               int(env.cell.traffic["check_sample"]), must=big)
-    hyper = {k: cfg["solver"][k] for k in (
-        "sa_t0", "sa_rc", "p_adm_w", "p_adm_h", "swap_moves", "exchange_every",
-        "ladder_min", "ladder_max", "p_kind")}
     mismatches = 0
     t0 = time.perf_counter()
     for j in picked:
         p, s = keys[j]
         a = tasks[(p, s)]
-        want = replay_sa_s(refs[p], s, chains, iters,
-                           penalty=cfg["solver"]["inventory_penalty"], **hyper)
+        want = env.rules.replay(refs[p], s, cfg)
         same = (a.cost == want["cost"]
                 and canonical(a.bins, a.kinds) == canonical(want["bins"], want["kinds"])
                 and [float(x) for x in a.trace] == [float(x) for x in want["trace"]]
@@ -544,7 +539,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     warm, drive = LOOPS[entry]
     algorithm, max_seconds, solver = solver_settings(cell.config)
     env = Env(cell, program_problems(cell.config), algorithm, max_seconds,
-              solver, backend, int(seed), traced=bool(trace))
+              solver, backend, int(seed), traced=bool(trace), rules=solver_file(algorithm))
     warm(env)
     gc.collect()
 

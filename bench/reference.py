@@ -12,14 +12,17 @@ things:
 * ``audit`` states what an answer claims and what the reference finds for
   it: every buffer placed exactly once, no bin over ``max_items``, every
   kind in range, the reported cost equal to the cost of the packing, the
-  last trace point equal to the penalized cost, and the chain-step budget
-  spent in full;
+  last trace point equal to the penalized cost, and the budget (chain
+  steps or generations) spent in full;
 * ``replay_sa_s`` anneals the problem again with SA-S (buffer-swap moves,
   per-bin RAM-kind flips, a Lundy-Mees temperature ladder over the chains,
   Metropolis acceptance on the inventory-penalized cost, best-chain
   exchange) from the same seed, drawing the seed's random stream in the
   documented order.  Its answer is the one the packer has to give: the
-  same packing, cost and improvement trace.
+  same packing, cost and improvement trace;
+* ``replay_ga_nfd`` does the same with GA-NFD (NFD seeding, the NFD repack
+  as mutation, layer-weighted fitness, tournament selection with elitism)
+  on a one-kind problem.
 
 The replay covers the settings the benchmark's cells use (no intra-layer
 constraint, patience and wall cap off).  It raises on any other setting
@@ -39,17 +42,21 @@ class ReferenceProblem:
     """One packing problem as the reference sees it."""
 
     def __init__(self, rows, max_items: int, kinds, counts, name: str = ""):
-        widths, depths = [], []
-        for n_pe, (n_simd, depth, wbits) in rows:
+        widths, depths, layers = [], [], []
+        for layer, (n_pe, (n_simd, depth, wbits)) in enumerate(rows):
             widths += [int(n_simd) * int(wbits)] * int(n_pe)
             depths += [int(depth)] * int(n_pe)
+            layers += [layer] * int(n_pe)  # a shape row is one layer
         self.name = name
         self.widths = np.asarray(widths, dtype=np.int64)
         self.depths = np.asarray(depths, dtype=np.int64)
+        self.layers = layers
+        self.widths_py, self.depths_py = widths, depths
         self.n = len(widths)
         self.max_items = int(max_items)
         self.modes = [tuple((int(a), int(b)) for a, b in k["modes"]) for k in kinds]
         caps = [int(k["capacity_bits"]) for k in kinds]
+        self.capacity_bits = caps
         unit = reduce(math.gcd, caps)
         self.weights = np.asarray([c // unit for c in caps], dtype=np.int64)
         self.counts = np.asarray(counts, dtype=np.int64)
@@ -98,8 +105,9 @@ class ReferenceProblem:
         return hit
 
     def geometry(self, bins) -> tuple[np.ndarray, np.ndarray]:
-        w = np.asarray([max(int(self.widths[i]) for i in b) for b in bins], np.int64)
-        h = np.asarray([sum(int(self.depths[i]) for i in b) for b in bins], np.int64)
+        ws, ds = self.widths_py, self.depths_py
+        w = np.asarray([max(ws[i] for i in b) for b in bins], np.int64)
+        h = np.asarray([sum(ds[i] for i in b) for b in bins], np.int64)
         return w, h
 
 
@@ -129,7 +137,7 @@ def audit(prob: ReferenceProblem, bins, kinds, cost, trace_last, iterations,
     if prob.n_kinds > 1 and float(trace_last) != penalized:
         faults.append(f"last trace point {trace_last} != {penalized}")
     if int(iterations) != int(expected_iterations):
-        faults.append(f"{iterations} chain steps != {expected_iterations}")
+        faults.append(f"{iterations} iterations != {expected_iterations}")
     return faults
 
 
@@ -145,8 +153,7 @@ def _nfd_bins(prob: ReferenceProblem, order, rng, p_adm_w, p_adm_h):
     when the bin has room and the kind-0 grid gap shrinks (or a draw below
     ``p_adm_h`` admits it anyway), and its width matches (or a draw below
     ``p_adm_w`` admits it).  Draws happen only where the rule needs them."""
-    widths = [int(x) for x in prob.widths]
-    depths = [int(x) for x in prob.depths]
+    widths, depths = prob.widths_py, prob.depths_py
     bins, cur = [], []
     cur_w = cur_h = 0
     for i in order:
@@ -426,3 +433,119 @@ def replay_sa_s(
         trace=trace,
         iterations=C * int(max_iterations),
     )
+
+
+# ---------------------------------------------------------- GA-NFD replay
+class _Packing:
+    """One individual: its bins, and each bin's cost, mapping efficiency
+    (stored bits over the capacity of its primitives) and distinct layers."""
+
+    __slots__ = ("bins", "cost", "eff", "layers")
+
+    def __init__(self, bins, cost, eff, layers):
+        self.bins, self.cost, self.eff, self.layers = bins, cost, eff, layers
+
+    @classmethod
+    def of(cls, prob: ReferenceProblem, bins) -> "_Packing":
+        w, h = prob.geometry(bins)
+        zero = np.zeros(len(bins), dtype=np.int64)
+        prim = prob.primitives(w, h, zero)
+        ws, ds = prob.widths_py, prob.depths_py
+        bits = np.asarray([sum(ws[i] * ds[i] for i in b) for b in bins], dtype=np.int64)
+        return cls(list(bins), prob.unit_costs(w, h, zero),
+                   bits / (prim * float(prob.capacity_bits[0])),
+                   np.asarray([len({prob.layers[i] for i in b}) for b in bins],
+                              dtype=np.int64))
+
+    def total(self) -> int:
+        return int(self.cost.sum())
+
+    def fitness(self, layer_weight: float) -> float:
+        f = float(self.total())
+        if layer_weight > 0.0:
+            f += layer_weight * (float(self.layers.sum()) / len(self.bins))
+        return f
+
+
+def _nfd_mutation(prob, ind: _Packing, rng, threshold, max_bins, extra_frac,
+                  p_adm_w, p_adm_h) -> _Packing:
+    """Algorithm 1 as a mutation: the bins that map worst (below
+    ``threshold``, at most ``max_bins`` of them, ties broken by a draw) and
+    a random ``extra_frac`` of the rest are emptied; their buffers, shuffled,
+    are packed again by NFD and appended after the kept bins."""
+    n = len(ind.bins)
+    mask = np.zeros(n, dtype=bool)
+    below = np.flatnonzero(ind.eff < threshold)
+    if len(below) > max_bins:
+        jitter = 1e-9 * rng.random(len(below))
+        below = below[np.argsort(ind.eff[below] + jitter)][:max_bins]
+    mask[below] = True
+    if extra_frac > 0.0:
+        mask |= rng.random(n) < extra_frac
+    if not mask.any():
+        mask[rng.integers(n)] = True
+    pool = np.asarray([i for j in np.flatnonzero(mask) for i in ind.bins[j]],
+                      dtype=np.int64)
+    rng.shuffle(pool)
+    new = _Packing.of(prob, _nfd_bins(prob, pool, rng, p_adm_w, p_adm_h))
+    keep = ~mask
+    return _Packing([ind.bins[j] for j in np.flatnonzero(keep)] + new.bins,
+                    np.concatenate([ind.cost[keep], new.cost]),
+                    np.concatenate([ind.eff[keep], new.eff]),
+                    np.concatenate([ind.layers[keep], new.layers]))
+
+
+def replay_ga_nfd(
+    prob: ReferenceProblem,
+    seed: int,
+    n_pop: int,
+    max_generations: int,
+    n_tour: int = 5,
+    p_mut: float = 0.4,
+    p_adm_w: float = 0.0,
+    p_adm_h: float = 0.1,
+    nfd_threshold: float = 0.95,
+    nfd_extra_frac: float = 0.01,
+    nfd_max_bins: int = 12,
+    layer_weight: float = 0.01,
+) -> dict:
+    """The GA-NFD answer for ``seed`` on a one-kind problem: ``{"cost",
+    "bins", "kinds", "trace", "iterations"}``.
+
+    A population of NFD packings (width-sorted for even ``k``); then, per
+    generation, each individual mutated with probability ``p_mut``, the
+    best tracked on the raw cost, and a tournament of ``n_tour`` drawn with
+    replacement per slot on the fitness (cost plus ``layer_weight`` times
+    the mean distinct layers per bin), the fittest kept in slot 0.  The
+    trace is the best cost at the start, after each improvement, and once
+    more at the end."""
+    if prob.n_kinds != 1:
+        raise ValueError("the GA-NFD replay covers one-kind problems only")
+    rng = np.random.default_rng(seed)
+    pop = [_Packing.of(prob, _nfd_solution(prob, rng, p_adm_w, p_adm_h, k % 2 == 0)[0])
+           for k in range(n_pop)]
+    costs = np.asarray([p.total() for p in pop], dtype=np.float64)
+    fits = np.asarray([p.fitness(layer_weight) for p in pop])
+    g = int(np.argmin(costs))
+    best, best_cost = pop[g], int(costs[g])
+    trace = [best_cost]
+    for _ in range(int(max_generations)):
+        for i in range(n_pop):
+            if rng.random() < p_mut:
+                pop[i] = _nfd_mutation(prob, pop[i], rng, nfd_threshold, nfd_max_bins,
+                                       nfd_extra_frac, p_adm_w, p_adm_h)
+                costs[i] = pop[i].total()
+                fits[i] = pop[i].fitness(layer_weight)
+        g = int(np.argmin(costs))
+        if costs[g] < best_cost:
+            best, best_cost = pop[g], int(costs[g])
+            trace.append(best_cost)
+        idx = rng.integers(n_pop, size=(n_pop, n_tour))
+        winners = idx[np.arange(n_pop), np.argmin(fits[idx], axis=1)]
+        winners[0] = int(np.argmin(fits))
+        pop = [pop[int(w)] for w in winners]
+        costs, fits = costs[winners], fits[winners]
+    trace.append(best_cost)
+    return dict(cost=best_cost, bins=[[int(i) for i in b] for b in best.bins],
+                kinds=[0] * len(best.bins), trace=trace,
+                iterations=int(max_generations))

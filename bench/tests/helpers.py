@@ -10,18 +10,25 @@ from bench import harness
 def shrink(cell: harness.Cell, rows: int = 3, per_row: int = 5, steps: int = 12,
            counts=None, replay_all: bool = False) -> harness.Cell:
     """Every accelerator cut to its first ``rows`` shape rows of at most
-    ``per_row`` buffers each; four chains, ``steps`` steps, an exchange every
-    five.  ``counts`` replaces every device's RAM counts; ``replay_all`` has
-    the check replay every distinct task instead of a sample."""
+    ``per_row`` buffers each; SA-S runs four chains of ``steps`` steps with
+    an exchange every five, GA-NFD a population of eight for ``steps``
+    generations.  ``counts`` replaces the RAM counts of every device with as
+    many kinds (a one-kind device keeps its own); ``replay_all`` has the
+    check replay every distinct task instead of a sample."""
     cfg = dict(cell.config)
     cfg["accelerators"] = {
         name: [[min(int(n), per_row), shape] for n, shape in acc[:rows]]
         for name, acc in cfg["accelerators"].items()
     }
-    cfg["n_chains"], cfg["max_iterations"] = 4, steps
-    cfg["solver"] = dict(cfg["solver"], exchange_every=5)
+    if "max_generations" in cfg:
+        cfg["max_generations"] = steps
+        cfg["solver"] = dict(cfg["solver"], n_pop=8)
+    else:
+        cfg["n_chains"], cfg["max_iterations"] = 4, steps
+        cfg["solver"] = dict(cfg["solver"], exchange_every=5)
     if counts is not None:
-        cfg["devices"] = {d: dict(v, counts=list(counts)) for d, v in cfg["devices"].items()}
+        cfg["devices"] = {d: dict(v, counts=list(counts)) if len(v["kinds"]) == len(counts)
+                          else v for d, v in cfg["devices"].items()}
     traffic = dict(cell.traffic)
     if replay_all:
         traffic["check_sample"] = 10**6

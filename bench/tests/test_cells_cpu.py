@@ -26,6 +26,32 @@ def test_cell_runs_end_to_end_on_the_cpu(cell):
     assert all(c["value"] <= c["limit"] for c in line["checks"].values())
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_traced_run_spans_its_own_kernel_alone(cell, monkeypatch):
+    """The dispatch spans see the cell's solver's kernel and no other: the
+    SA cells never call the GA's fitness kernel."""
+    from bench import tracing
+
+    made = []
+
+    class Spans(tracing.DispatchSpans):
+        def __init__(self):
+            super().__init__()
+            made.append(self)
+
+    monkeypatch.setattr(tracing, "DispatchSpans", Spans)
+    line = run_tiny(ROOT, cell, seed=2**31 + 13, seconds=0.4, trace=True)
+    assert line["correct"] is True
+    kernel = "binpack_fitness" if cell.endswith(".ga") else "binpack_sa_step"
+    assert made[0].calls and {c[0] for c in made[0].calls} == {kernel}
+    layer = {m["name"] for m in harness.load_cell(ROOT, cell).per_layer}
+    # on the CPU the trace has no device plane: the span readers read, the
+    # device readers find nothing
+    assert {n for n in layer if n.startswith(("host_share", "dispatch_us"))} <= set(
+        line["metrics"])
+    assert not {n for n in line["metrics"] if n.startswith(("device_idle", "binpack_"))}
+
+
 def test_same_seed_same_inputs():
     from bench import generator
 

@@ -74,16 +74,34 @@ OCCUPANCY = '''def read(run):
 '''
 
 
-@pytest.mark.parametrize("entry", ["pack", "serve"])
+@pytest.mark.parametrize("entry", ["pack", "serve", "ga-nfd"])
 def test_a_cell_made_of_data_files_runs_without_a_harness_edit(tmp_path, entry):
     """A later PR adds a cell with a traffic file, metric readers and
-    entries only: a closed loop of single solves, or an open loop of
-    service requests."""
+    entries only: a closed loop of single solves, an open loop of service
+    requests, or a GA-NFD configuration of its own."""
     for sub in ("configs", "traffic", "metrics"):
         shutil.copytree(ROOT / "bench" / sub, tmp_path / "bench" / sub)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     name = f"table1-dse.{entry}-mix"
-    if entry == "pack":
+    config = "table1-zu7ev-u50"
+    if entry == "ga-nfd":
+        # CNV-W1A1 (Table 1) on BRAM18 alone, GA-NFD at the packer's defaults
+        cfg = json.loads((ROOT / "bench" / "configs" / "rn152-w1a2-bram18.json").read_text())
+        table1 = json.loads((ROOT / "bench" / "configs" / "table1-zu7ev-u50.json").read_text())
+        config = "cnv-w1a1-bram18"
+        cfg.update(name=config, accelerators={"CNV-W1A1": table1["accelerators"]["CNV-W1A1"]},
+                   problems=[{"accelerator": "CNV-W1A1", "device": "BRAM18-unbounded"}],
+                   max_generations=5, solver=dict(cfg["solver"], n_pop=6))
+        (tmp_path / "bench" / "configs" / f"{config}.json").write_text(json.dumps(cfg))
+        bench["configs"].append({"name": config, "source": "https://arxiv.org/abs/2003.12449",
+                                 "file": f"bench/configs/{config}.json", "reduced": [],
+                                 "why": "a test configuration"})
+        mix = json.loads((ROOT / "bench" / "traffic" / "single-pack-ga.json").read_text())
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if m["name"].endswith(".ga") or m["name"] == "solve_s":
+                m["workloads"].append(name)
+        want = {"solve_s", "setup_s"}
+    elif entry == "pack":
         mix = json.loads((ROOT / "bench" / "traffic" / "single-pack-sa.json").read_text())
         for m in bench["end_to_end"] + bench["per_layer"]:
             if m["name"].endswith("solve") or m["name"] == "solve_s":
@@ -101,7 +119,7 @@ def test_a_cell_made_of_data_files_runs_without_a_harness_edit(tmp_path, entry):
                                    "workloads": [name]})
         want = {"request_p80_ms", "setup_s"}
     (tmp_path / "bench" / "traffic" / f"{entry}-mix.json").write_text(json.dumps(mix))
-    bench["workloads"].append({"name": name, "config": "table1-zu7ev-u50",
+    bench["workloads"].append({"name": name, "config": config,
                                "traffic": f"{entry}-mix", "chips": 1, "why": "a test cell"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     line = run_tiny(tmp_path, name, seed=7, seconds=0.5)
@@ -110,3 +128,13 @@ def test_a_cell_made_of_data_files_runs_without_a_harness_edit(tmp_path, entry):
     if entry == "serve":
         traced = run_tiny(tmp_path, name, seed=8, seconds=0.5, trace=True)
         assert set(traced["metrics"]) == {"serve_batch_occupancy"}
+
+
+@pytest.mark.parametrize("algorithm,refusal", [("ga-x", "no solver file"),
+                                               ("ga-nfd", "one-kind devices only")])
+def test_a_solver_that_cannot_be_checked_is_refused_before_a_run(algorithm, refusal):
+    """An algorithm with no solver file, or GA-NFD on the U50's two kinds."""
+    cell = harness.load_cell(ROOT, "rn152-u50.sa-fleet")
+    cell.config = dict(cell.config, solver=dict(cell.config["solver"], algorithm=algorithm))
+    with pytest.raises(harness.CellError, match=refusal):
+        harness.run(cell, 1, 0.1, False, 0.0, allow_cpu=True)

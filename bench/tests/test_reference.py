@@ -10,13 +10,19 @@ from pathlib import Path
 import pytest
 
 from bench import harness
-from bench.reference import audit, canonical, replay_sa_s
+from bench.reference import audit, canonical, replay_ga_nfd, replay_sa_s
 
 ROOT = Path(__file__).resolve().parents[2]
 TABLE1 = json.loads((ROOT / "bench" / "configs" / "table1-zu7ev-u50.json").read_text())
 HYPER = {k: TABLE1["solver"][k] for k in (
     "sa_t0", "sa_rc", "p_adm_w", "p_adm_h", "swap_moves", "ladder_min",
     "ladder_max", "p_kind")}
+
+
+BRAM18 = json.loads((ROOT / "bench" / "configs" / "rn152-w1a2-bram18.json").read_text())
+GA = {k: BRAM18["solver"][k] for k in (
+    "n_tour", "p_mut", "p_adm_w", "p_adm_h", "nfd_threshold", "nfd_extra_frac",
+    "nfd_max_bins", "layer_weight")}
 
 
 def _pair(index):
@@ -44,6 +50,30 @@ def test_replay_matches_the_program(index, seed, chains, steps, exchange):
         want["bins"], want["kinds"])
     assert audit(ref, want["bins"], want["kinds"], want["cost"], want["trace"][-1],
                  want["iterations"], chains * steps) == []
+
+
+@pytest.mark.parametrize("backend", ["python", "pallas"])
+@pytest.mark.parametrize("seed", [1, 7, 2**31 + 5])
+def test_ga_nfd_replay_matches_the_program(seed, backend):
+    """RN152-W1A2's first four shape rows, at most 24 buffers each, on
+    BRAM18 alone; a population of 12 for 40 generations."""
+    import repro.core as c
+
+    cfg = dict(BRAM18, accelerators={
+        "RN152-W1A2": [[min(n, 24), shape] for n, shape in
+                       BRAM18["accelerators"]["RN152-W1A2"][:4]]})
+    prob, ref = harness.program_problems(cfg)[0], harness.reference_problems(cfg)[0]
+    res = c.pack(prob, "ga-nfd", seed=seed, n_pop=12, max_generations=40,
+                 backend=backend, max_seconds=1e12, patience=10**12, **GA)
+    want = replay_ga_nfd(ref, seed, 12, 40, **GA)
+    assert res.params["backend"] == backend
+    assert int(res.cost) == want["cost"]
+    assert [x for _, x in res.trace] == want["trace"] and len(want["trace"]) > 2
+    assert res.iterations == want["iterations"] == 40
+    assert canonical(res.solution.bins, res.solution.kinds) == canonical(
+        want["bins"], want["kinds"])
+    assert audit(ref, want["bins"], want["kinds"], want["cost"], want["trace"][-1],
+                 want["iterations"], 40) == []
 
 
 def test_audit_finds_each_broken_guarantee():

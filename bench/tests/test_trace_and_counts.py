@@ -8,8 +8,10 @@ device plane holds 40 ``sa_step_deltas_kinds_pallas`` custom-calls,
 66 111 ns in all, and 40 ``jit_sa_step_deltas_kinds_pallas`` modules."""
 from __future__ import annotations
 
+import types
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from bench import opcount, tracing
@@ -90,3 +92,30 @@ def test_counts_at_hand_computed_shapes():
     assert opcount.fitness(50, 10, [BRAM18]) == (500 * 38, 500 * 12)
     assert opcount.portfolio_step(75, 1200, 64, 4, [BRAM18, URAM288]) == (
         90000 * 50 + 256 * 102, 90000 * 16 + 6400)
+
+
+def test_fitness_calls_are_spanned_with_their_shapes():
+    from repro.kernels.binpack_fitness import ops
+
+    orig = ops.population_costs
+    spans = tracing.DispatchSpans()
+    spans.install()
+    try:
+        w = np.ones((2, 5, 7), dtype=np.int32)  # a problem axis: one flattened call
+        ops.population_costs(w, w, backend="ref")
+        ops.population_costs(w[0], w[0], backend="ref", kinds=np.zeros_like(w[0]),
+                             kind_tables=((1, tuple(BRAM18)), (16, tuple(URAM288))))
+    finally:
+        spans.remove()
+    assert ops.population_costs is orig
+    assert spans.calls == [("binpack_fitness", 10, 7, [BRAM18]),
+                           ("binpack_fitness", 5, 7, [BRAM18, URAM288])]
+
+
+def test_fitness_roofline_counts_its_calls():
+    t = types.SimpleNamespace(kernel_events={"binpack_fitness": 2},
+                              kernel_seconds={"binpack_fitness": 4e-6})
+    calls = [("binpack_fitness", 75, 2253, [BRAM18])] * 2 + [
+        ("binpack_sa_step", 64, 4, [BRAM18, URAM288])]
+    share = tracing.hbm_roofline_pct(t, calls, "binpack_fitness", peak("TPU v5 lite"))
+    assert share == pytest.approx(100 * (2 * 75 * 2253 * 12 / 819e9) / 4e-6)

@@ -6,8 +6,11 @@ Spans exist only in ``--trace 1`` runs.  They are the harness's own
 on the host's clock: ``bench.window`` around the measured window,
 ``bench.solve`` / ``bench.sweep`` / ``bench.batch`` around each call into
 the program's entry points, and ``bench.dispatch.<kernel>`` around each call
-into the kernel-dispatch layer (``repro/kernels/*/ops.py``), from the
-host's arrays in to the host's arrays out.
+into the kernel-dispatch layer (``repro/kernels/*/ops.py``):
+``bench.dispatch.sa_step`` from the host's arrays in to the host's arrays
+out, ``bench.dispatch.fitness`` from the device arrays the GA puts to the
+result ready on the device (the GA makes the puts before the call and reads
+the result back after it).
 
 The reduction reads the trace's ``.xplane.pb`` with
 ``jax.profiler.ProfileData``: on each ``/device:TPU:<n>`` plane the
@@ -39,9 +42,10 @@ KERNEL_OPS = {
 class DispatchSpans:
     """Spans around the kernel-dispatch calls, with each call's shape.
 
-    ``install`` replaces ``sa_step_deltas`` in its ops module; the engines
-    look it up there at call time, so every step of the window passes
-    through the span.  ``remove`` puts the original back."""
+    ``install`` replaces ``sa_step_deltas`` and ``population_costs`` in
+    their ops modules; the engines look them up there at call time, so every
+    step and generation of the window passes through a span.  ``remove``
+    puts the originals back."""
 
     def __init__(self):
         self.calls: list[tuple[str, int, int, list]] = []
@@ -53,27 +57,49 @@ class DispatchSpans:
         import jax
         import numpy as np
 
-        mod = importlib.import_module("repro.kernels.binpack_sa_step.ops")
-        orig = mod.sa_step_deltas
-        sig = inspect.signature(orig)
         calls = self.calls
+
+        def kind_modes(a):
+            kt = a["kind_tables"]
+            return [list(m) for _, m in kt] if kt is not None else [list(a["modes"])]
+
+        step_mod = importlib.import_module("repro.kernels.binpack_sa_step.ops")
+        step = step_mod.sa_step_deltas
+        step_sig = inspect.signature(step)
 
         def sa_step_deltas(*args, **kwargs):
             if np.ndim(args[0] if args else kwargs["old_w"]) != 2:
-                return orig(*args, **kwargs)  # reshapes, then calls back in
+                return step(*args, **kwargs)  # reshapes, then calls back in
             with jax.profiler.TraceAnnotation(DISPATCH + "sa_step"):
-                out = orig(*args, **kwargs)
-            bound = sig.bind(*args, **kwargs)
+                out = step(*args, **kwargs)
+            bound = step_sig.bind(*args, **kwargs)
             bound.apply_defaults()
             a = bound.arguments
-            kt = a["kind_tables"]
-            kind_modes = [list(m) for _, m in kt] if kt is not None else [list(a["modes"])]
             rows, touched = np.shape(a["old_w"])
-            calls.append(("binpack_sa_step", int(rows), int(touched), kind_modes))
+            calls.append(("binpack_sa_step", int(rows), int(touched), kind_modes(a)))
             return out
 
-        mod.sa_step_deltas = sa_step_deltas
-        self._undo.append((mod, "sa_step_deltas", orig))
+        fit_mod = importlib.import_module("repro.kernels.binpack_fitness.ops")
+        fit = fit_mod.population_costs
+        fit_sig = inspect.signature(fit)
+
+        def population_costs(*args, **kwargs):
+            if np.ndim(args[0] if args else kwargs["widths"]) != 2:
+                return fit(*args, **kwargs)  # reshapes, then calls back in
+            with jax.profiler.TraceAnnotation(DISPATCH + "fitness"):
+                out = jax.block_until_ready(fit(*args, **kwargs))
+            bound = fit_sig.bind(*args, **kwargs)
+            bound.apply_defaults()
+            a = bound.arguments
+            population, bins = np.shape(a["widths"])
+            calls.append(("binpack_fitness", int(population), int(bins), kind_modes(a)))
+            return out
+
+        for mod, name, orig, new in (
+                (step_mod, "sa_step_deltas", step, sa_step_deltas),
+                (fit_mod, "population_costs", fit, population_costs)):
+            setattr(mod, name, new)
+            self._undo.append((mod, name, orig))
 
     def remove(self) -> None:
         while self._undo:
@@ -260,7 +286,7 @@ def hbm_roofline_pct(t: TraceSummary, calls, kernel: str, peaks: dict) -> float 
 
     if t is None or not t.kernel_events.get(kernel):
         return None
-    count = {"binpack_sa_step": opcount.sa_step}[kernel]
+    count = {"binpack_sa_step": opcount.sa_step, "binpack_fitness": opcount.fitness}[kernel]
     nbytes = sum(count(rows, touched, km)[1] for k, rows, touched, km in calls
                  if k == kernel)
     if nbytes == 0:
