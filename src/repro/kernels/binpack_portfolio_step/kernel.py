@@ -34,7 +34,9 @@ def portfolio_step_pallas(
         W.reshape(-1, nb), H.reshape(-1, nb), modes, interpret
     )
     totals = jnp.sum(per_bin, axis=1).reshape(W.shape[:-1])
-    deltas = sa_step_deltas_pallas(old_w, old_h, new_w, new_h, modes, interpret)
+    deltas = sa_step_deltas_pallas(
+        jnp.stack([old_w, old_h, new_w, new_h]), modes, interpret
+    )
     return totals, deltas
 
 
@@ -50,6 +52,7 @@ def portfolio_step_kinds_pallas(
     )
     totals = jnp.sum(per_bin, axis=1).reshape(W.shape[:-1])
     deltas = sa_step_deltas_kinds_pallas(
-        old_w, old_h, old_k, new_w, new_h, new_k, kind_tables, interpret
+        jnp.stack([old_w, old_h, old_k, new_w, new_h, new_k]),
+        kind_tables, interpret,
     )
     return totals, deltas

@@ -1,9 +1,11 @@
 """Dispatcher for the fused SA step: per-chain delta cost + Metropolis rule.
 
 ``sa_step_deltas`` is the hot primitive of the batched multi-chain annealer:
-four padded (C, T) int32 matrices (touched-bin geometry before/after one
-buffer-swap move per chain) reduce to a (C,) integer delta-cost vector in a
-single call.  Backends:
+four (C, T) int32 planes (touched-bin geometry before/after one buffer-swap
+move per chain), six with the RAM-kind lanes, reduce to a (C,) integer
+delta-cost vector in a single call.  On the jax backends the planes cross to
+the device as one stacked (4 or 6, C, T) int32 block: one host-to-device put
+a step, whatever the plane count.  Backends:
 
 * ``"python"`` — vectorized numpy; no JAX import on the hot path.  At SA's
   tiny per-step shapes (T = 2 * swap_moves) this is the fastest option on a
@@ -24,6 +26,8 @@ backend-bit-parity contract pins that exact stream and rounding.  Fusing the
 compare into the f32 kernel would break parity for ~1-ulp boundary cases.
 """
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -116,34 +120,33 @@ def sa_step_deltas(
             new_c = _bin_costs_numpy(new_w, new_h, modes)
             old_c = _bin_costs_numpy(old_w, old_h, modes)
         return np.sum(new_c - old_c, axis=-1)
-    import jax.numpy as jnp
-
     if backend == "ref":
-        fn = _jit_ref_kinds() if hetero else _jit_ref()
+        fn = _jit_ref(hetero)
     elif backend == "pallas":
         from .kernel import sa_step_deltas_kinds_pallas, sa_step_deltas_pallas
 
         fn = sa_step_deltas_kinds_pallas if hetero else sa_step_deltas_pallas
     else:
         raise ValueError(f"unknown backend {backend!r}; options: {BACKENDS}")
-    # the puts are made before the call so that each part of the round trip
+    if hetero:
+        planes, table = (old_w, old_h, old_k, new_w, new_h, new_k), kind_tables
+    else:
+        planes, table = (old_w, old_h, new_w, new_h), tuple(modes)
+    # the put is made before the call so that each part of the round trip
     # is a span of its own (repro.spans)
     with span("repro.dispatch.h2d"):
-        if hetero:
-            args = (
-                jnp.asarray(old_w), jnp.asarray(old_h), jnp.asarray(old_k),
-                jnp.asarray(new_w), jnp.asarray(new_h), jnp.asarray(new_k),
-                kind_tables,
-            )
-        else:
-            args = (
-                jnp.asarray(old_w), jnp.asarray(old_h),
-                jnp.asarray(new_w), jnp.asarray(new_h), tuple(modes),
-            )
+        block = _put(np.stack(planes, dtype=np.int32))
     with span("repro.dispatch.launch"):
-        out = fn(*args)
+        out = fn(block, table)
     with span("repro.dispatch.d2h"):
         return np.asarray(out, dtype=np.int64)
+
+
+def _put(block: np.ndarray):
+    """The step's one host-to-device copy: the stacked plane block."""
+    import jax
+
+    return jax.device_put(block)
 
 
 _SHARD_CACHE: dict = {}
@@ -185,13 +188,13 @@ def _sa_step_deltas_sharded(
             )
 
             if hetero:
-                def body(ow, oh, ok, nw, nh, nk):
+                def body(*planes):
                     return sa_step_deltas_kinds_pallas(
-                        ow, oh, ok, nw, nh, nk, kind_tables
+                        jnp.stack(planes), kind_tables
                     )
             else:
-                def body(ow, oh, nw, nh):
-                    return sa_step_deltas_pallas(ow, oh, nw, nh, modes)
+                def body(*planes):
+                    return sa_step_deltas_pallas(jnp.stack(planes), modes)
         fn = _SHARD_CACHE[key] = row_shard(mesh, body)
     if hetero:
         args = (old_w, old_h, old_k, new_w, new_h, new_k)
@@ -216,38 +219,16 @@ def metropolis_mask(d_e, temps, u) -> np.ndarray:
     return (d < 0) | ((t > 0) & (u < p))
 
 
-_REF_JIT = None
-_REF_KINDS_JIT = None
+@functools.cache
+def _jit_ref(hetero: bool):
+    """The jit'd oracle over a stacked plane block (kind tables or modes
+    static)."""
+    import jax
 
+    from .ref import sa_step_deltas_kinds_ref, sa_step_deltas_ref
 
-def _jit_ref():
-    global _REF_JIT
-    if _REF_JIT is None:
-        import functools
-
-        import jax
-
-        from .ref import sa_step_deltas_ref
-
-        _REF_JIT = functools.partial(jax.jit, static_argnames=("modes",))(
-            sa_step_deltas_ref
-        )
-    return _REF_JIT
-
-
-def _jit_ref_kinds():
-    global _REF_KINDS_JIT
-    if _REF_KINDS_JIT is None:
-        import functools
-
-        import jax
-
-        from .ref import sa_step_deltas_kinds_ref
-
-        _REF_KINDS_JIT = functools.partial(
-            jax.jit, static_argnames=("kind_tables",)
-        )(sa_step_deltas_kinds_ref)
-    return _REF_KINDS_JIT
+    ref = sa_step_deltas_kinds_ref if hetero else sa_step_deltas_ref
+    return jax.jit(lambda block, table: ref(*block, table), static_argnums=1)
 
 
 def resolve_auto() -> str:

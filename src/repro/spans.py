@@ -19,8 +19,9 @@ The spans, from the outside in (docs/DESIGN.md section 17):
 * ``repro.sa.propose`` / ``repro.sa.accept`` - one annealing step before
   and after its delta-cost request;
 * ``repro.dispatch.h2d`` / ``.launch`` / ``.d2h`` - one kernel call of
-  ``sa_step_deltas``: the host-to-device puts, the jitted call, and the
-  blocking read-back.
+  ``sa_step_deltas``: the one host-to-device put of the stacked plane
+  block (4 or 6 planes, stacked on the host inside the span), the jitted
+  call, and the blocking read-back.
 """
 from __future__ import annotations
 

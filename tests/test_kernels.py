@@ -148,6 +148,99 @@ def test_sa_step_deltas_kinds_backends_agree(c, t, rng):
     assert np.array_equal(py, direct)
 
 
+# ------------------------------------------- one stacked block per SA step
+def _two_kinds():
+    from repro.core.problem import BRAM18, URAM288
+
+    return ((1, BRAM18.modes), (16, URAM288.modes))
+
+
+def _sa_planes(shape, kinds, seed):
+    """Random touched-bin planes with zero-width (empty) slots in every row,
+    and, with a problem axis, one whole padded (all-empty) problem."""
+    rng = np.random.default_rng(seed)
+    planes = []
+    for _ in range(2):  # old, new
+        w = rng.integers(0, 80, shape).astype(np.int32)
+        w[rng.random(shape) < 0.3] = 0
+        w[..., -1] = 0
+        h = np.where(w > 0, rng.integers(1, 70_000, shape), 0).astype(np.int32)
+        k = rng.integers(0, 2, shape).astype(np.int32)
+        if len(shape) == 3:
+            w[-1] = h[-1] = k[-1] = 0
+        planes.append((w, h, k))
+    (ow, oh, ok), (nw, nh, nk) = planes
+    kw = dict(old_k=ok, new_k=nk, kind_tables=_two_kinds()) if kinds else {}
+    return (ow, oh, nw, nh), kw
+
+
+_STACK_SHAPES = [(13, 5), (8, 130), (1, 1), (3, 5, 7), (2, 9, 130)]
+
+
+@pytest.mark.parametrize("shape", _STACK_SHAPES, ids=str)
+@pytest.mark.parametrize("kinds", [False, True], ids=["plain", "kinds"])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_sa_step_stacked_block_matches_python(backend, kinds, shape):
+    """The jax backends, fed one stacked plane block, equal the host numpy
+    path bit for bit: rows off the sublane tile, slots off the lane width,
+    empty slots and a padded problem, with and without kind lanes."""
+    args, kw = _sa_planes(shape, kinds, seed=len(shape) * 100 + shape[-1])
+    py = sa_step_deltas(*args, backend="python", **kw)
+    got = sa_step_deltas(*args, backend=backend, **kw)
+    assert got.shape == shape[:-1] and got.dtype == np.int64
+    np.testing.assert_array_equal(got, py)
+    if len(shape) == 3:
+        np.testing.assert_array_equal(got[-1], 0)
+
+
+@pytest.mark.parametrize("ndim", [2, 3])
+@pytest.mark.parametrize("kinds", [False, True], ids=["plain", "kinds"])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_sa_step_one_put_per_call(backend, kinds, ndim, monkeypatch):
+    """One host-to-device put per call on the jax backends: the planes go
+    over as one (4 or 6, R, T) int32 block."""
+    from repro.kernels.binpack_sa_step import ops
+
+    puts = []
+    put = ops._put
+
+    def counting_put(block):
+        puts.append((block.shape, block.dtype))
+        return put(block)
+
+    monkeypatch.setattr(ops, "_put", counting_put)
+    shape = (2, 5, 4) if ndim == 3 else (10, 4)
+    args, kw = _sa_planes(shape, kinds, seed=7)
+    ops.sa_step_deltas(*args, backend=backend, **kw)
+    assert puts == [((6 if kinds else 4, 10, 4), np.dtype(np.int32))]
+
+
+@pytest.mark.parametrize("kinds", [False, True], ids=["plain", "kinds"])
+def test_sa_step_dispatch_calls_named_entries(kinds, monkeypatch):
+    """The Pallas entries the dispatcher calls are the jitted functions the
+    benchmark's trace reduction finds by name (``KERNEL_OPS``)."""
+    from bench.tracing import KERNEL_OPS
+
+    from repro.kernels.binpack_sa_step import kernel
+
+    names = KERNEL_OPS["binpack_sa_step"]
+    called = []
+    for name in names:
+        entry = getattr(kernel, name)
+
+        def spy(block, table, _entry=entry):
+            called.append(_entry.__name__)
+            assert f"@jit_{_entry.__name__}" in _entry.lower(block, table).as_text()
+            return _entry(block, table)
+
+        monkeypatch.setattr(kernel, name, spy)
+    args, kw = _sa_planes((9, 4), kinds, seed=3)
+    sa_step_deltas(*args, backend="pallas", **kw)
+    assert called == [
+        "sa_step_deltas_kinds_pallas" if kinds else "sa_step_deltas_pallas"
+    ]
+
+
 def test_metropolis_mask_edge_cases():
     d = np.array([-5.0, 0.0, 2.0, 2.0, 1.0])
     t = np.array([0.0, 1.0, 1e12, 1e-12, 0.0])

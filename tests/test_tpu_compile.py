@@ -99,13 +99,12 @@ def test_binpack_fitness_compiles(prob, one_chip, kinds):
 
 @pytest.mark.parametrize("kinds", [False, True], ids=["plain", "kinds"])
 def test_binpack_sa_step_compiles(prob, one_chip, kinds):
-    x = _i32((SA_ROWS, SA_WIDTH), one_chip)
     if kinds:
-        low = sa_step_deltas_kinds_pallas.lower(
-            x, x, x, x, x, x, prob.kind_tables, False
-        )
+        block = _i32((6, SA_ROWS, SA_WIDTH), one_chip)
+        low = sa_step_deltas_kinds_pallas.lower(block, prob.kind_tables, False)
     else:
-        low = sa_step_deltas_pallas.lower(x, x, x, x, c.BRAM18_MODES, False)
+        block = _i32((4, SA_ROWS, SA_WIDTH), one_chip)
+        low = sa_step_deltas_pallas.lower(block, c.BRAM18_MODES, False)
     _assert_kernel(low)
 
 
