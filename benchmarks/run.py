@@ -17,8 +17,8 @@ import time
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="engine|hetero|sa|portfolio|racing|dse|sweep_sharded|"
-                         "serve|table3|table4|fig45|tpu|seqpack|kernels|"
+                    help="engine|hetero|sa|portfolio|racing|dse|serve|"
+                         "table3|table4|fig45|tpu|seqpack|kernels|"
                          "roofline")
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",
@@ -36,7 +36,6 @@ def main(argv=None) -> None:
         bench_roofline,
         bench_seqpack,
         bench_serve,
-        bench_sweep_sharded,
         bench_table3,
         bench_table4,
         bench_tpu_packing,
@@ -73,9 +72,6 @@ def main(argv=None) -> None:
         "portfolio": lambda: bench_engine.run_portfolio(quick=quick, smoke=smoke),
         "racing": lambda: bench_racing.run(quick=quick, smoke=smoke),
         "dse": lambda: bench_dse.run(quick=quick, smoke=smoke),
-        "sweep_sharded": lambda: bench_sweep_sharded.run(
-            quick=quick, smoke=smoke
-        ),
         "serve": lambda: bench_serve.run(quick=quick, smoke=smoke),
         "table3": lambda: bench_table3.run(
             accelerators=small, budgets=budgets, seeds=t3_seeds

@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from concurrent.futures import ThreadPoolExecutor
 from typing import Sequence
 
 import numpy as np
@@ -127,8 +126,7 @@ class SweepResult:
     #: sweep-level counters (PR 8): ``solved`` unique tasks solved this call,
     #: ``cache_hits`` unique tasks served from the cache / checkpoint store,
     #: ``dedup_hits`` positions collapsed by fingerprint dedup (so
-    #: ``solved + cache_hits + dedup_hits == len(problems)``), plus the
-    #: execution-shape knob ``n_shards``.
+    #: ``solved + cache_hits + dedup_hits == len(problems)``).
     params: dict = dataclasses.field(default_factory=dict)
 
     @property
@@ -217,115 +215,15 @@ def _group_by_cost_model(indices, problems) -> list[list[int]]:
     return list(groups.values())
 
 
-def shard_chunks(n: int, k: int) -> list[list[int]]:
-    """Contiguous balanced split of ``range(n)`` into ``min(k, n)`` chunks.
-
-    The first ``n % k`` chunks carry one extra row.  Contiguity is
-    load-bearing: shard boundaries become plain row slices of the canonical
-    merged checkpoint layout (``resume.merge_block_states``), so snapshots
-    restore onto ANY shard count (docs/DESIGN.md section 14).
-    """
-    k = max(1, min(int(k), n))
-    base, rem = divmod(n, k)
-    out, lo = [], 0
-    for i in range(k):
-        size = base + (1 if i < rem else 0)
-        out.append(list(range(lo, lo + size)))
-        lo += size
-    return out
-
-
-def _shard_devices(mesh, n_chunks: int, backend: str):
-    """Round-robin device pins for host-split shards (``n_shards > 1`` AND a
-    mesh): shard ``i`` dispatches on ``devices[i % len]``.  With one chunk
-    the mesh goes down the ``shard_map`` path instead, and the ``"python"``
-    backend never touches jax devices."""
-    if mesh is None or n_chunks <= 1 or backend not in ("ref", "pallas"):
-        return None
-    return list(mesh.devices.flat)
-
-
-def _solve_sa_group_sharded(
-    packer, probs, rngs, backend, n_shards, mesh, gkeys=None, ck=None
-) -> list:
-    """One cost-model group annealed as ``n_shards`` concurrent sub-fleets.
-
-    Each shard is a contiguous problem slice started as its own
-    `_block_start` block and advanced on a thread; per-problem trajectories
-    are fleet-composition-independent (each live problem consumes only its
-    own RNG stream and frozen problems never draw), so results are
-    bit-identical to the one-fleet lane — pinned in ``tests/test_sharded.py``.
-    Checkpoints are cut in the canonical MERGED layout
-    (`resume.merge_block_states`), identical to the unsharded snapshot, so a
-    crashed sharded sweep may resume at any other shard count.
-    """
-    chunks = shard_chunks(len(probs), n_shards)
-    shard_mesh = mesh if len(chunks) == 1 else None
-    devices = _shard_devices(mesh, len(chunks), backend)
-    sts = [
-        packer._block_start(
-            [probs[j] for j in c], [rngs[j] for j in c],
-            [[] for _ in c], backend, mesh=shard_mesh,
-        )
-        for c in chunks
-    ]
-    gd = None
-    if ck is not None:
-        from .resume import group_digest, merge_block_states
-
-        gd = group_digest(gkeys)
-        ck.restore_block_shards(gd, sts, packer.patience)
-
-    def run(si, limit):
-        st = sts[si]
-        if st.done:
-            return
-        if devices is not None:
-            import jax
-
-            with jax.default_device(devices[si % len(devices)]):
-                packer._block_run(st, limit)
-        else:
-            packer._block_run(st, limit)
-
-    while not all(st.done for st in sts):
-        if ck is None:
-            limit = None  # each shard drains to its budgets in one call
-        else:
-            it = max(st.it for st in sts if not st.done)
-            limit = (it // ck.every + 1) * ck.every
-        live = [i for i, st in enumerate(sts) if not st.done]
-        if len(live) == 1:
-            run(live[0], limit)
-        else:
-            with ThreadPoolExecutor(max_workers=len(live)) as ex:
-                for _ in ex.map(lambda si: run(si, limit), live):
-                    pass
-        if ck is not None and not all(st.done for st in sts):
-            arrays, extra = merge_block_states(sts)
-            ck.save_progress(group=gd, arrays=arrays, engine=extra)
-    blocks = []
-    for st in sts:
-        blocks.extend(packer._block_finish(st))
-    return blocks
-
-
 def _solve_sa_groups(
-    packer, groups, problems, seeds, backend, keys=None, ck=None,
-    n_shards=1, mesh=None,
+    packer, groups, problems, seeds, backend, keys=None, ck=None, mesh=None,
 ) -> dict[int, PackingResult]:
     out: dict[int, PackingResult] = {}
     for group in groups:
         probs = [problems[i] for i in group]
         rngs = [np.random.default_rng(seeds[i]) for i in group]
         packer._hetero = probs[0].n_kinds > 1
-        if n_shards > 1 and len(group) > 1:
-            gkeys = [keys[i] for i in group] if keys is not None else None
-            blocks = _solve_sa_group_sharded(
-                packer, probs, rngs, backend, n_shards, mesh,
-                gkeys=gkeys, ck=ck,
-            )
-        elif ck is None:
+        if ck is None:
             blocks = packer._anneal_block(
                 probs, rngs, [[] for _ in group], backend, mesh=mesh
             )
@@ -377,8 +275,7 @@ def _lockstep_drain(pairs, gen_limit=None, mesh=None) -> bool:
 
 
 def _solve_ga_groups(
-    packer, groups, problems, seeds, backend, keys=None, ck=None,
-    n_shards=1, mesh=None,
+    packer, groups, problems, seeds, backend, keys=None, ck=None, mesh=None,
 ) -> dict[int, PackingResult]:
     out: dict[int, PackingResult] = {}
     for group in groups:
@@ -388,50 +285,18 @@ def _solve_ga_groups(
             )
             for i in group
         ]
-        chunks = shard_chunks(len(runs), n_shards)
-        shard_mesh = mesh if len(chunks) == 1 else None
-        devices = _shard_devices(mesh, len(chunks), backend)
-        totals = stacked_population_costs(runs, backend, mesh=shard_mesh)
+        totals = stacked_population_costs(runs, backend, mesh=mesh)
         for run, tot in zip(runs, totals):
             packer._eval_init(run, tot)
         # drive the GA segment API directly (ga.lockstep_begin / apply /
         # finish): per generation, one mutation phase across every live run,
         # one stacked fitness call per population-size batch, then
         # selection — the same phases the fleet-native portfolio fuses with
-        # SA work at its barriers (docs/DESIGN.md section 13).  With
-        # ``n_shards > 1`` the group's runs split into contiguous lockstep
-        # sub-packs, each drained on its own thread: fitness values are
-        # per-individual, so stack membership never changes any trajectory
-        # (pinned in tests/test_sharded.py).
-        pair_chunks = [[(packer, runs[j]) for j in c] for c in chunks]
-
-        def drain_chunk(ci, glimit):
-            pc = pair_chunks[ci]
-            if devices is not None:
-                import jax
-
-                with jax.default_device(devices[ci % len(devices)]):
-                    while _lockstep_drain(pc, glimit):
-                        pass
-            else:
-                while _lockstep_drain(pc, glimit, mesh=shard_mesh):
-                    pass
-
-        def drain_all(glimit):
-            live = [
-                ci for ci, c in enumerate(chunks)
-                if any(not runs[j].done for j in c)
-            ]
-            if len(live) <= 1:
-                for ci in live:
-                    drain_chunk(ci, glimit)
-            else:
-                with ThreadPoolExecutor(max_workers=len(live)) as ex:
-                    for _ in ex.map(lambda ci: drain_chunk(ci, glimit), live):
-                        pass
-
+        # SA work at its barriers (docs/DESIGN.md section 13).
+        pairs = [(packer, run) for run in runs]
         if ck is None:
-            drain_all(None)
+            while _lockstep_drain(pairs, mesh=mesh):
+                pass
         else:
             from .resume import encode_ga_group, group_digest
 
@@ -442,7 +307,8 @@ def _solve_ga_groups(
                 if not live:
                     break
                 glimit = (min(live) // ck.every + 1) * ck.every
-                drain_all(glimit)
+                while _lockstep_drain(pairs, glimit, mesh=mesh):
+                    pass
                 if all(run.done for run in runs):
                     break
                 arrays, extras = encode_ga_group(runs)
@@ -459,8 +325,8 @@ def _solve_ga_groups(
 
 def _solve_positions(
     todo, problems, seeds, algorithm, *, seed=0, max_seconds=30.0,
-    intra_layer=False, backend="auto", keys=None, ck=None, n_shards=1,
-    mesh=None, hyper=None,
+    intra_layer=False, backend="auto", keys=None, ck=None, mesh=None,
+    hyper=None,
 ) -> tuple[dict[int, PackingResult], int]:
     """Solve the given positions of ``problems`` through the right lane.
 
@@ -491,13 +357,13 @@ def _solve_positions(
         groups = _group_by_cost_model(todo, problems)
         solved = _solve_sa_groups(
             packer, groups, problems, seeds, resolved, keys=keys, ck=ck,
-            n_shards=n_shards, mesh=mesh,
+            mesh=mesh,
         )
     elif algorithm in _GA_LOCKSTEP and resolved in ("ref", "pallas"):
         groups = _group_by_cost_model(todo, problems)
         solved = _solve_ga_groups(
             packer, groups, problems, seeds, resolved, keys=keys, ck=ck,
-            n_shards=n_shards, mesh=mesh,
+            mesh=mesh,
         )
     else:
         # serial fallback: scalar/legacy engines, heuristics, portfolio.
@@ -524,7 +390,6 @@ def solve_batch(
     max_seconds: float = 30.0,
     intra_layer: bool = False,
     backend: str = "auto",
-    n_shards: int = 1,
     mesh=None,
     **hyper,
 ) -> list[PackingResult]:
@@ -556,7 +421,7 @@ def solve_batch(
     solved, _ = _solve_positions(
         range(len(problems)), problems, seeds, algorithm, seed=seed,
         max_seconds=max_seconds, intra_layer=intra_layer, backend=backend,
-        n_shards=int(n_shards), mesh=mesh, hyper=hyper,
+        mesh=mesh, hyper=hyper,
     )
     return [solved[i] for i in range(len(problems))]
 
@@ -574,7 +439,6 @@ def pack_sweep(
     checkpoint_every: int = 256,
     resume: bool = False,
     on_checkpoint=None,
-    n_shards: int = 1,
     mesh=None,
     **hyper,
 ) -> SweepResult:
@@ -613,20 +477,12 @@ def pack_sweep(
     fault-injection hook).  Resumed-from-checkpoint candidates count as
     cache hits, not fresh solves.
 
-    Scaling past one device (PR 8, docs/DESIGN.md section 14):
-
-    * ``n_shards`` — split each batched group into that many contiguous
-      sub-fleets (SA) / lockstep sub-packs (GA), advanced concurrently on
-      threads.  Per-problem trajectories are fleet-composition-independent,
-      so any shard count is **bit-identical** to ``n_shards=1`` (pinned in
-      ``tests/test_sharded.py``); checkpoints are cut in a canonical merged
-      layout, so a crashed sharded sweep resumes at any other shard count.
-    * ``mesh`` — a 1-D ``("prob",)`` device mesh
-      (:func:`repro.launch.mesh.make_sweep_mesh`).  With ``n_shards=1`` the
-      batched kernels row-shard each step over the mesh via ``shard_map``;
-      with ``n_shards > 1`` the sub-fleets are instead pinned round-robin
-      to the mesh's devices.  Jax backends ("ref"/"pallas") only; the
-      ``"python"`` backend and the serial fallback lane ignore both knobs.
+    Scaling past one device (PR 8, docs/DESIGN.md section 14): ``mesh`` —
+    a 1-D ``("prob",)`` device mesh
+    (:func:`repro.launch.mesh.make_sweep_mesh`) — row-shards each batched
+    kernel step over its devices via ``shard_map``, bit-identically (pinned
+    in ``tests/test_sharded.py``).  Jax backends ("ref"/"pallas") only; the
+    ``"python"`` backend and the serial fallback lane ignore it.
     """
     problems = list(problems)
     if not problems:
@@ -639,9 +495,6 @@ def pack_sweep(
         if len(seeds) != len(problems):
             raise ValueError("seeds must align with problems")
     hyper = normalize_hyper(algorithm, hyper)
-    n_shards = int(n_shards)
-    if n_shards < 1:
-        raise ValueError("n_shards must be >= 1")
     t_start = time.perf_counter()
 
     keys = _task_keys(problems, algorithm, seeds, intra_layer, backend,
@@ -679,8 +532,7 @@ def pack_sweep(
         solved, n_groups = _solve_positions(
             rep.values(), problems, seeds, algorithm, seed=seed,
             max_seconds=max_seconds, intra_layer=intra_layer,
-            backend=backend, keys=keys, ck=ck, n_shards=n_shards, mesh=mesh,
-            hyper=hyper,
+            backend=backend, keys=keys, ck=ck, mesh=mesh, hyper=hyper,
         )
         for i, res in solved.items():
             results_by_key[keys[i]] = res
@@ -700,6 +552,5 @@ def pack_sweep(
             solved=len(fresh),
             cache_hits=len(set(keys)) - len(fresh),
             dedup_hits=len(problems) - len(set(keys)),
-            n_shards=n_shards,
         ),
     )

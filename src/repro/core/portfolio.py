@@ -50,7 +50,6 @@ import numpy as np
 
 from repro.kernels.platform import pallas_interpret
 
-from .dse import _shard_devices, shard_chunks
 from .ga import (
     GeneticPacker,
     lockstep_apply,
@@ -153,71 +152,24 @@ class _SAFleetGroup:
     chain slot (envelope padding never affects trajectories — DESIGN.md
     section 10).
 
-    ``n_shards`` splits the islands into contiguous sub-fleets, one block
-    state per shard, advanced concurrently on threads at every barrier;
-    ``mesh`` row-shards each fleet step over a ``("prob",)`` device mesh
-    (with one shard) or pins the sub-fleets round-robin to the mesh's
-    devices (with several).  Both are pure execution-shape knobs: each
-    island consumes only its own RNG stream, so any shard count is
-    bit-identical to the one-fleet layout (docs/DESIGN.md section 14,
-    pinned in ``tests/test_sharded.py``)."""
+    ``mesh`` row-shards each fleet step over a ``("prob",)`` device mesh —
+    an execution-shape knob only: each island consumes only its own RNG
+    stream, so the result is bit-identical to the unsharded fleet
+    (docs/DESIGN.md section 14, pinned in ``tests/test_sharded.py``)."""
 
-    def __init__(self, packer, prob, rngs, backend, n_shards=1, mesh=None):
+    def __init__(self, packer, prob, rngs, backend, mesh=None):
         self.packer = packer
-        chunks = shard_chunks(len(rngs), n_shards)
-        shard_mesh = mesh if len(chunks) == 1 else None
-        self.devices = _shard_devices(mesh, len(chunks), backend)
-        self.sts = [
-            packer._block_start(
-                [prob] * len(c), [rngs[j] for j in c], [[] for _ in c],
-                backend, n_slots=prob.n, mesh=shard_mesh,
-            )
-            for c in chunks
-        ]
-        self._starts = [c[0] for c in chunks]
-
-    @property
-    def st(self):
-        """The lone block state of an unsharded fleet (the common case and
-        the fused-dispatch requirement); multi-shard fleets have no single
-        state — address islands through :meth:`state_of`."""
-        if len(self.sts) != 1:
-            raise RuntimeError(
-                f"fleet is split into {len(self.sts)} shards; use state_of(j)"
-            )
-        return self.sts[0]
-
-    def state_of(self, j: int):
-        """(block state, local row) owning island ``j``."""
-        for st, lo in zip(reversed(self.sts), reversed(self._starts)):
-            if j >= lo:
-                return st, j - lo
-        raise IndexError(j)
-
-    def _run_shard(self, si: int, limit: int | None) -> None:
-        st = self.sts[si]
-        if st.done:
-            return
-        if self.devices is not None:
-            import jax
-
-            with jax.default_device(self.devices[si % len(self.devices)]):
-                self.packer._block_run(st, limit)
-        else:
-            self.packer._block_run(st, limit)
+        self.st = packer._block_start(
+            [prob] * len(rngs), list(rngs), [[] for _ in rngs],
+            backend, n_slots=prob.n, mesh=mesh,
+        )
 
     def advance(self, limit: int | None) -> bool:
-        live = [i for i, st in enumerate(self.sts) if not st.done]
-        if not live:
+        if self.st.done:
             return False
-        before = [self.sts[i].it for i in live]
-        if len(live) == 1:
-            self._run_shard(live[0], limit)
-        else:
-            with ThreadPoolExecutor(max_workers=len(live)) as ex:
-                for _ in ex.map(lambda i: self._run_shard(i, limit), live):
-                    pass
-        return any(self.sts[i].it > b for i, b in zip(live, before))
+        before = self.st.it
+        self.packer._block_run(self.st, limit)
+        return self.st.it > before
 
 
 class _FleetIsland:
@@ -230,20 +182,19 @@ class _FleetIsland:
         self.eliminated = False
 
     def done(self) -> bool:
-        st, j = self.group.state_of(self.j)
+        st, j = self.group.st, self.j
         return st.done or self.packer._block_frozen(st, j)
 
     def extend(self, it_limit: int) -> None:
-        st, _ = self.group.state_of(self.j)
-        self.packer._block_extend(st, it_limit)
+        self.packer._block_extend(self.group.st, it_limit)
 
     def eliminate(self) -> None:
-        st, j = self.group.state_of(self.j)
+        st, j = self.group.st, self.j
         self.packer._block_eliminate(st, j)
         self.eliminated = True
 
     def raw(self) -> tuple[int, int]:
-        st, j = self.group.state_of(self.j)
+        st, j = self.group.st, self.j
         cost = int(st.gbest_cost[j])
         if st.hetero:
             ovf = int(st.batch.overflow_rows(
@@ -254,26 +205,25 @@ class _FleetIsland:
         return cost, ovf
 
     def best_solution(self) -> Solution:
-        st, j = self.group.state_of(self.j)
+        st, j = self.group.st, self.j
         return decode_chain_items(
             st.probs[j], st.g_items[j], st.g_counts[j],
             st.g_kinds[j] if st.hetero else None,
         )
 
     def migrate_in(self, sol: Solution) -> bool:
-        st, j = self.group.state_of(self.j)
+        st, j = self.group.st, self.j
         return self.packer._block_migrate(st, j, sol)
 
     def trace(self) -> list:
-        st, j = self.group.state_of(self.j)
+        st, j = self.group.st, self.j
         return st.traces[j]
 
     def offset(self, t0: float) -> float:
-        st, _ = self.group.state_of(self.j)
-        return st.t_start - t0
+        return self.group.st.t_start - t0
 
     def iterations(self) -> int:
-        (st, j), c = self.group.state_of(self.j), self.packer.n_chains
+        st, j, c = self.group.st, self.j, self.packer.n_chains
         return int(st.steps[j * c : (j + 1) * c].sum())
 
     def truncated(self) -> bool:
@@ -281,7 +231,7 @@ class _FleetIsland:
         neither frozen (patience) nor out of iteration budget."""
         if self.eliminated:
             return False
-        st, _ = self.group.state_of(self.j)
+        st = self.group.st
         return st.done and not st.frozen and st.it < self.packer.max_iterations
 
 
@@ -623,7 +573,7 @@ def _group_backend(group) -> str:
     """The evaluation backend a group's kernel calls resolved to (the scalar
     loop calls no kernel: ``"python"``)."""
     if isinstance(group, _SAFleetGroup):
-        return group.sts[0].backend
+        return group.st.backend
     if isinstance(group, _GAGroup):
         return group.pairs[0][1].backend
     return group.st.backend if group.single else "python"
@@ -663,7 +613,7 @@ def _advance_fused(
     Returns (fleet_progressed, ga_progressed)."""
     from repro.kernels.binpack_portfolio_step.ops import portfolio_step
 
-    packer, st = fleet.packer, fleet.sts[0]  # fuse requires one shard
+    packer, st = fleet.packer, fleet.st
     before = st.it
     gen = None if st.done else packer._block_gen(st, fleet_limit)
     req = next(gen, None) if gen is not None else None
@@ -719,7 +669,6 @@ def pack_portfolio(
     checkpoint_every: int = 1,
     resume: bool = False,
     on_checkpoint=None,
-    n_shards: int = 1,
     mesh=None,
     auto: bool = False,
     race_grid=None,
@@ -811,18 +760,12 @@ def pack_portfolio(
     has no thread pool (see :func:`pack_portfolio_threads` for the legacy
     engine, kept as a benchmark baseline).
 
-    Scaling past one device (PR 8, docs/DESIGN.md section 14): ``n_shards``
-    splits the sa-s island fleet into that many contiguous sub-fleets
-    advanced concurrently between barriers, and ``mesh`` (a
+    Scaling past one device (PR 8, docs/DESIGN.md section 14): ``mesh`` (a
     :func:`repro.launch.mesh.make_sweep_mesh` device mesh) row-shards the
-    fleet's annealing steps and the GA pack's stacked fitness calls over
-    its ``("prob",)`` axis (one shard) or pins the sub-fleets round-robin
-    to its devices (several shards).  Both are execution-shape knobs only:
-    every shard count and mesh is **bit-identical** to the default
-    single-device run, and checkpoints are cut in a canonical merged layout
-    so a run may resume at a different shard count (pinned in
-    ``tests/test_sharded.py``).  Fused dispatch needs the fleet in one
-    piece, so ``n_shards > 1`` disables it.
+    sa-s fleet's annealing steps and the GA pack's stacked fitness calls
+    over its ``("prob",)`` axis.  It is an execution-shape knob only: every
+    mesh is **bit-identical** to the default single-device run (pinned in
+    ``tests/test_sharded.py``).
 
     Crash safety (docs/DESIGN.md section 12): with ``checkpoint_dir`` the
     run cuts a durable snapshot of every island's engine state (plus the
@@ -969,9 +912,6 @@ def pack_portfolio(
             fleet_members.setdefault(_sa_fleet_key(packer, resolved), []).append(
                 (k, packer)
             )
-    n_shards = int(n_shards)
-    if n_shards < 1:
-        raise ValueError("n_shards must be >= 1")
     if ga_pairs:
         groups.append(_GAGroup(ga_pairs, mesh=mesh))
     for members in fleet_members.values():
@@ -980,7 +920,6 @@ def pack_portfolio(
             prob,
             [np.random.default_rng(p.seed) for _, p in members],
             members[0][1]._resolve_backend(),
-            n_shards=n_shards,
             mesh=mesh,
         )
         groups.append(fleet)
@@ -1075,11 +1014,10 @@ def pack_portfolio(
     fuse = (
         scheduler == "concurrent" and fi is not None and gi is not None
         and sum(isinstance(g, _SAFleetGroup) for g in groups) == 1
-        and len(groups[fi].sts) == 1  # fused dispatch needs one fleet shard
         and (
             fused if fused is not None
             else (
-                groups[fi].sts[0].backend in ("ref", "pallas")
+                groups[fi].st.backend in ("ref", "pallas")
                 and all(r.backend in ("ref", "pallas") and r.batched
                         for _, r in groups[gi].pairs)
             )
@@ -1232,7 +1170,6 @@ def pack_portfolio(
             backend=backend,
             seed=seed,
             scheduler=scheduler,
-            n_shards=n_shards,
             fused=bool(fuse),
             backends={lab: _group_backend(g) for lab, g in zip(labels, groups)},
             interpret=any(pallas_interpret(_group_backend(g)) for g in groups),

@@ -692,7 +692,7 @@ class SimulatedAnnealingPacker:
         envelope padding never affects trajectories, see DESIGN.md §10).
         ``mesh`` (a ``("prob",)`` sweep mesh) row-shards the delta kernel on
         jax backends — a start-derived constant, never serialized (resume
-        may restore onto a different mesh/shard count, DESIGN.md §14)."""
+        may restore onto a different mesh, DESIGN.md §14)."""
         with span("repro.sa.start"):
             st = _BlockState()
             st.mesh = mesh if backend in ("ref", "pallas") else None

@@ -156,6 +156,61 @@ def test_sweep_fleet_matches_standalone_per_problem():
         assert r.solution.cost() == r.solution.cost_full() == r.cost
 
 
+def _problem(seed: int, hetero: bool = False) -> PackingProblem:
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(12, 30))
+    bufs = [
+        Buffer(width=int(rng.integers(1, 80)), depth=int(rng.integers(1, 40_000)),
+               layer=int(rng.integers(0, 5)))
+        for _ in range(n)
+    ]
+    ocm = (
+        OCMInventory((BRAM18, URAM288), (n * 3, 8), name=f"dev{seed}")
+        if hetero else None
+    )
+    return PackingProblem(bufs, max_items=4, name=f"sh{seed}", ocm=ocm)
+
+
+def _record(sw) -> list[tuple]:
+    return [
+        (r.cost, r.solution.state_dict(), r.iterations,
+         [cc for _, cc in r.trace])
+        for r in sw.results
+    ]
+
+
+_SPLIT_KW = {
+    "sa-s": dict(max_seconds=1e9, patience=10**9, backend="python",
+                 max_iterations=400, n_chains=4),
+    "ga-nfd": dict(max_seconds=1e9, patience=10**9, backend="ref",
+                   max_generations=8, n_pop=10),
+}
+
+
+@pytest.mark.parametrize("k", [2, 3, 8])
+@pytest.mark.parametrize(
+    "algorithm,hetero",
+    [("sa-s", False), ("sa-s", True), ("ga-nfd", False), ("ga-nfd", True)],
+)
+def test_sweep_split_fleet_bit_identical(algorithm, hetero, k):
+    """A problem's trajectory does not depend on which fleet it shares:
+    solving k contiguous slices of a sweep as separate fleets gives, bit
+    for bit, the results of the one fleet.  Cost-model grouping, the
+    dedup/cache lanes and PackingService micro-batching all rely on it."""
+    probs = [_problem(s, hetero) for s in (11, 12, 13, 14, 15)]
+    seeds = [5, 1, 8, 3, 2]
+    kw = _SPLIT_KW[algorithm]
+    whole = c.pack_sweep(probs, algorithm, seeds=seeds, **kw)
+    assert whole.n_groups == 1
+    split = []
+    for part in np.array_split(np.arange(len(probs)), min(k, len(probs))):
+        lo, hi = int(part[0]), int(part[-1]) + 1
+        split += _record(
+            c.pack_sweep(probs[lo:hi], algorithm, seeds=seeds[lo:hi], **kw)
+        )
+    assert split == _record(whole)
+
+
 def test_sweep_hetero_fleet_mixed_devices():
     """ZU7EV and U50 share kind tables but not counts: one group, exact
     per-problem inventory penalties, parity incl. kind lanes."""
@@ -252,7 +307,6 @@ def test_sweep_dedup_and_cache():
     assert sw.params["solved"] == 2
     assert sw.params["dedup_hits"] == 1
     assert sw.params["cache_hits"] == 0
-    assert sw.params["n_shards"] == 1
     # a second sweep over a superset is served entirely from the cache
     sw2 = c.pack_sweep([prob, other, clone], "sa-s", seed=0, n_chains=3,
                        cache=cache, **_SA_KW)
