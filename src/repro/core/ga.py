@@ -46,6 +46,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.kernels.platform import on_tpu, pallas_interpret
+from repro.spans import span
 
 from .nfd import nfd_from_scratch, nfd_repack
 from .problem import (
@@ -474,31 +475,32 @@ class GeneticPacker:
         in place).  Returns the mutated indices; on the batched path their
         kernel costs are applied afterwards via `_apply_costs`."""
         mutated: list[int] = []
-        for i in range(self.n_pop):
-            if run.rng.random() < self.p_mut:
-                run.pop[i] = self._mutate(
-                    run.pop[i], run.rng, use_cache=run.use_cache,
-                    hetero=run.hetero,
-                )
-                if run.ovfs is not None:
-                    run.ovfs[i] = run.pop[i].inventory_overflow()
-                if run.batched:
-                    run.pop[i].fill_geometry(run.W[i], run.H[i])
-                    if run.Km is not None:
-                        run.pop[i].fill_kinds(run.Km[i])
-                    mutated.append(i)
-                elif run.use_cache:
-                    run.costs[i] = run.pop[i].cost()
-                    run.fits[i] = fitness(
-                        run.pop[i], self.layer_weight, cost=run.costs[i],
-                        inventory_penalty=run.inv_pen,
-                        overflow=None if run.ovfs is None else run.ovfs[i],
+        with span("repro.ga.mutate"):
+            for i in range(self.n_pop):
+                if run.rng.random() < self.p_mut:
+                    run.pop[i] = self._mutate(
+                        run.pop[i], run.rng, use_cache=run.use_cache,
+                        hetero=run.hetero,
                     )
-                else:
-                    run.costs[i] = run.pop[i].cost_full()
-                    run.fits[i] = self._fitness_legacy(
-                        run.pop[i], run.costs[i], run.hetero
-                    )
+                    if run.ovfs is not None:
+                        run.ovfs[i] = run.pop[i].inventory_overflow()
+                    if run.batched:
+                        run.pop[i].fill_geometry(run.W[i], run.H[i])
+                        if run.Km is not None:
+                            run.pop[i].fill_kinds(run.Km[i])
+                        mutated.append(i)
+                    elif run.use_cache:
+                        run.costs[i] = run.pop[i].cost()
+                        run.fits[i] = fitness(
+                            run.pop[i], self.layer_weight, cost=run.costs[i],
+                            inventory_penalty=run.inv_pen,
+                            overflow=None if run.ovfs is None else run.ovfs[i],
+                        )
+                    else:
+                        run.costs[i] = run.pop[i].cost_full()
+                        run.fits[i] = self._fitness_legacy(
+                            run.pop[i], run.costs[i], run.hetero
+                        )
         return mutated
 
     def _apply_costs(self, run: "_GARun", totals, mutated: list[int]) -> None:

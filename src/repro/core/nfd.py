@@ -117,10 +117,15 @@ def nfd_repack(
     mask = select_repack_bins(
         sol, rng, threshold, max_bins, extra_frac, use_cache=use_cache
     )
-    keep = [b for b, m in zip(sol.bins, mask) if not m]
-    pool = np.asarray(
-        [i for b, m in zip(sol.bins, mask) if m for i in b], dtype=np.int64
-    )
+    # The work below is proportional to the selected bins (a dozen to a few
+    # dozen), not to the whole packing: ``keep`` is a C-level list copy with
+    # the selected bins deleted from the back, ``pool`` gathers their items.
+    sel = np.flatnonzero(mask).tolist()
+    bins = sol.bins
+    keep = bins.copy()
+    for bi in reversed(sel):
+        del keep[bi]
+    pool = np.asarray([i for bi in sel for i in bins[bi]], dtype=np.int64)
     rng.shuffle(pool)
     if intra_layer:
         # stable sort by layer after the shuffle: random order within a layer,
@@ -133,26 +138,25 @@ def nfd_repack(
     # kept bins carry their RAM kinds into the child; freshly repacked bins
     # start on kind 0 (the finest-grained primitive) — the engines' kind
     # moves and inventory penalty re-balance them
+    kept = ~mask
     if not use_cache:
         if prob.n_kinds == 1:
             return Solution(prob, keep + new_bins)
-        kept_kinds = [int(k) for k, m in zip(sol.kinds, mask) if not m]
-        return Solution(
-            prob, keep + new_bins, kinds=kept_kinds + [0] * len(new_bins)
-        )
+        kinds = sol.kinds[kept].tolist() + [0] * len(new_bins)
+        return Solution(prob, keep + new_bins, kinds=kinds)
     # Kept bin lists are SHARED with the parent (persistent-structure style):
     # nothing in the engine mutates a bin list without copying the solution
     # first (buffer_swap works on a fresh copy()), so sharing is safe and
     # avoids an O(n) deep copy per mutation.  new_bins are fresh lists and
-    # their geometry rows start dirty.
+    # their geometry rows start dirty; the kept rows are clean, since
+    # ``select_repack_bins`` refreshed the parent's geometry.
     nk, nn = len(keep), len(new_bins)
     geom = np.empty((nk + nn, 6), dtype=np.int64)
-    geom[:nk] = sol._geom[~mask]
-    dirty = np.empty(nk + nn, dtype=bool)
-    dirty[:nk] = sol._dirty[~mask]
+    geom[:nk] = sol._geom[kept]
+    dirty = np.zeros(nk + nn, dtype=bool)
     dirty[nk:] = True
     kinds = np.zeros(nk + nn, dtype=np.int64)
-    kinds[:nk] = sol.kinds[~mask]
+    kinds[:nk] = sol.kinds[kept]
     return Solution._with_geometry(prob, keep + new_bins, geom, dirty, kinds)
 
 

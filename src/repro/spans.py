@@ -22,6 +22,8 @@ The spans, from the outside in (docs/DESIGN.md section 17):
   ``sa_step_deltas``: the one host-to-device put of the stacked plane
   block (4 or 6 planes, stacked on the host inside the span), the jitted
   call, and the blocking read-back.
+* ``repro.ga.mutate`` - one GA generation's mutations: every NFD repack or
+  buffer swap, and the mutated rows' geometry update.
 """
 from __future__ import annotations
 

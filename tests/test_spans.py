@@ -116,3 +116,28 @@ def test_python_backend_with_spans_off_imports_no_jax():
     out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
                          text=True, check=True)
     assert out.stdout.strip() == "False"
+
+
+@pytest.mark.parametrize("sweep", [False, True])
+def test_ga_mutate_span_once_per_generation(sweep, monkeypatch):
+    probs = [c.get_problem("CNV-W1A1"), c.get_problem("CNV-W2A2")]
+    kw = dict(n_pop=8, max_generations=6, backend="ref", max_seconds=1e12,
+              patience=10**12)
+
+    def solve():
+        if sweep:
+            return [_answer(r) for r in
+                    c.pack_sweep(probs, "ga-nfd", seeds=[1, 2], **kw).results]
+        return [_answer(c.pack(probs[0], "ga-nfd", seed=1, **kw))]
+
+    off = solve()
+    names = []
+
+    @contextlib.contextmanager
+    def record(name):
+        names.append(name)
+        yield
+
+    monkeypatch.setattr(spans, "_annotation", record)
+    assert solve() == off
+    assert names.count("repro.ga.mutate") == 6 * len(off)  # one per generation
